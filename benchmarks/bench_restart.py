@@ -169,21 +169,10 @@ def main():
     import jax
     from repro.serving import PagedServingEngine, replay_journal
 
-    # ---- oracle: the uninterrupted run (durability off) -------------
-    cfg, model, params = _model()
-    plan = _plan(JOURNAL_DIR)
-    import dataclasses
-    from repro.serving import DurabilityPolicy
-    engine = PagedServingEngine.from_plan(
-        model, dataclasses.replace(plan, durability=DurabilityPolicy()))
-    oracle_reqs = _requests(cfg)
-    oracle_stats = engine.run(oracle_reqs, params)
-    oracle = {r.rid: list(r.tokens) for r in oracle_reqs}
-    if oracle_stats["preemptions"] < 1:
-        raise SystemExit("restart smoke: the burst must preempt so the "
-                         "crash leaves spilled swap images to recover")
-
-    # ---- the crash: a child interpreter dies mid-burst --------------
+    # ---- the crash first: a child interpreter dies mid-burst --------
+    # It runs to its end before this process initialises any backend:
+    # one process at a time may hold an accelerator, so parent and child
+    # must never both have one.
     if os.path.isdir(JOURNAL_DIR):
         shutil.rmtree(JOURNAL_DIR)
     src_root = os.path.join(
@@ -208,6 +197,20 @@ def main():
     if crashed.plan is None:
         raise SystemExit("restart smoke: no serving_plan.json beside "
                          "the crashed journal")
+
+    # ---- oracle: the uninterrupted run (durability off) -------------
+    cfg, model, params = _model()
+    plan = _plan(JOURNAL_DIR)
+    import dataclasses
+    from repro.serving import DurabilityPolicy
+    engine = PagedServingEngine.from_plan(
+        model, dataclasses.replace(plan, durability=DurabilityPolicy()))
+    oracle_reqs = _requests(cfg)
+    oracle_stats = engine.run(oracle_reqs, params)
+    oracle = {r.rid: list(r.tokens) for r in oracle_reqs}
+    if oracle_stats["preemptions"] < 1:
+        raise SystemExit("restart smoke: the burst must preempt so the "
+                         "crash leaves spilled swap images to recover")
 
     # ---- recovery gates run on copies; JOURNAL_DIR stays the -------
     # ---- pristine post-crash state for the CI artifact -------------

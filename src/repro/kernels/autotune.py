@@ -306,10 +306,9 @@ def _fpd_vmem(problem: dict[str, Any], cfg: dict[str, int]) -> int:
     ps = cfg["page_size"]
     item = _itemsize(problem["dtype"])
     blocks = (2 * g * d + 2 * ps * d) * item        # q, out, k, v page tiles
-    mask = ps * 4                                   # int32 validity tile
     scratch = (2 * g + g * d) * 4                   # m, l, acc (f32)
     temps = 2 * g * ps * 4                          # s and p (f32)
-    return blocks + mask + scratch + temps
+    return blocks + scratch + temps
 
 
 def _fpd_candidates(problem: dict[str, Any]
@@ -348,23 +347,21 @@ def _fpd_inputs(problem_json: str, page_size: int):
     q = jax.random.normal(jax.random.PRNGKey(0),
                           (slots, 1, h, d)).astype(dtype)
     kp = jax.random.normal(jax.random.PRNGKey(1),
-                           (n_pages, page_size, kvh, d)).astype(dtype)
+                           (n_pages, kvh, page_size, d)).astype(dtype)
     vp = jax.random.normal(jax.random.PRNGKey(2),
-                           (n_pages, page_size, kvh, d)).astype(dtype)
+                           (n_pages, kvh, page_size, d)).astype(dtype)
     bt = 1 + jnp.arange(slots * blocks, dtype=jnp.int32).reshape(
         slots, blocks)
     # steady-state (worst-case) decode: every request near capacity
-    mask = jnp.broadcast_to(
-        jnp.arange(blocks * page_size)[None, :] < max_len,
-        (slots, blocks * page_size))
-    return q, kp, vp, bt, mask
+    sl = jnp.full((slots,), max_len - 1, jnp.int32)
+    return q, kp, vp, bt, sl
 
 
 def _fpd_runner(problem: dict[str, Any], cfg: dict[str, int],
                 interpret: bool) -> Callable[[], Any]:
-    q, kp, vp, bt, mask = _fpd_inputs(
+    q, kp, vp, bt, sl = _fpd_inputs(
         json.dumps(problem, sort_keys=True), cfg["page_size"])
-    return lambda: flash_decode_paged(q, kp, vp, bt, mask,
+    return lambda: flash_decode_paged(q, kp, vp, bt, sl,
                                       interpret=interpret)
 
 
@@ -400,10 +397,9 @@ def _pseg_vmem(problem: dict[str, Any], cfg: dict[str, int]) -> int:
     ps = problem["page_size"]
     item = _itemsize(problem["dtype"])
     blocks = (2 * g * d + 2 * ps * d) * item
-    mask = ps * 4
     scratch = (2 * g + g * d) * 4
     temps = 2 * g * ps * 4
-    return blocks + mask + scratch + temps
+    return blocks + scratch + temps
 
 
 def _pseg_candidates(problem: dict[str, Any]
@@ -426,21 +422,19 @@ def _pseg_fn(problem_json: str, seg_len: int, interpret: bool):
     q = jax.random.normal(jax.random.PRNGKey(0),
                           (slots, 1, h, d)).astype(dtype)
     kp = jax.random.normal(jax.random.PRNGKey(1),
-                           (n_pages, ps, kvh, d)).astype(dtype)
+                           (n_pages, kvh, ps, d)).astype(dtype)
     vp = jax.random.normal(jax.random.PRNGKey(2),
-                           (n_pages, ps, kvh, d)).astype(dtype)
+                           (n_pages, kvh, ps, d)).astype(dtype)
     bt = 1 + jnp.arange(slots * blocks, dtype=jnp.int32).reshape(
         slots, blocks)
-    n = blocks * ps
     # start half-full: the scan advances seq_lens like a real segment
     sl0 = jnp.full((slots,), max(1, max_len // 2), jnp.int32)
 
     def segment(sl):
         def step(carry, _):
             cur = carry
-            mask = jnp.arange(n)[None, :] < jnp.minimum(
-                cur + 1, max_len)[:, None]
-            out = flash_decode_paged(q, kp, vp, bt, mask,
+            out = flash_decode_paged(q, kp, vp, bt,
+                                     jnp.minimum(cur, max_len - 1),
                                      interpret=interpret)
             return jnp.minimum(cur + 1, max_len - 1), out[:, 0, 0, 0]
         sl, outs = jax.lax.scan(step, sl, None, length=seg_len)
@@ -521,9 +515,9 @@ def _fpr_inputs(problem_json: str):
     q = jax.random.normal(jax.random.PRNGKey(0),
                           (slots, s, h, d)).astype(dtype)
     kp = jax.random.normal(jax.random.PRNGKey(1),
-                           (n_pages, ps, kvh, d)).astype(dtype)
+                           (n_pages, kvh, ps, d)).astype(dtype)
     vp = jax.random.normal(jax.random.PRNGKey(2),
-                           (n_pages, ps, kvh, d)).astype(dtype)
+                           (n_pages, kvh, ps, d)).astype(dtype)
     bt = 1 + jnp.arange(slots * blocks, dtype=jnp.int32).reshape(
         slots, blocks)
     # worst case: every suffix sits at the end of a near-full prefix, so

@@ -11,6 +11,10 @@ shared prefix) in one dispatch, reading K/V — shared prefix and freshly
 scattered suffix alike — straight out of the page pool through the block
 table.
 
+The pool is head-major, ``(P, KV, page_size, D)``
+(serving/paged_cache.py), so each grid step DMAs one head's page as a
+contiguous ``(page_size, D)`` tile.
+
 Grid: ``(slots, kv_heads, q_tiles, blocks)``.  The innermost dimension
 walks the request's block table exactly like the paged decode kernel,
 reducing pages into the partial-softmax ``(m, l, acc)`` carry held in
@@ -25,8 +29,9 @@ the jnp oracle (pinned against each other in tests/test_paged.py), so
 the two paths cannot disagree about which (query, slot) pairs interact.
 Tiles with no live pair — a q tile past the request's suffix, a page
 beyond the causal frontier, an idle batch slot (``lens[r] == 0``) — skip
-their MXU work entirely (``pl.when``), which is what makes one padded
-dispatch serve a ragged admission batch.
+their MXU work entirely (``pl.when`` on a scalar test of the same
+predicate), which is what makes one padded dispatch serve a ragged
+admission batch.
 
 GQA uses the grouped-q fold of the decode kernels, extended to multiple
 query positions: q is laid out ``(R, KV, S * g, D)`` so the ``g`` query
@@ -48,8 +53,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_decode import (NEG_INF, online_softmax_finish,
+from repro.kernels.flash_decode import (online_softmax_finish,
                                         online_softmax_init)
+from repro.kernels.flash_decode_paged import online_softmax_page
 
 BQ = 32
 
@@ -68,38 +74,26 @@ def _ragged_prefill_kernel(bt_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
 
     off = off_ref[ri]
     ln = len_ref[ri]
-    # rows of the (bq * g, ps) score panel: row -> suffix-local q index
-    # (g adjacent rows share one query position), col -> slot in page j.
-    # Mirrors models/layers.py::ragged_prefill_attention_mask: a slot
-    # participates when its logical position <= the query's absolute
-    # position (causal over prefix + own suffix) and the query is live.
-    qrel = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq * g, ps),
-                                              0) // g
-    kv_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (bq * g, ps), 1)
-    live = (kv_pos <= off + qrel) & (qrel < ln)
+    # the tile holds a live pair iff its first query is valid and page j
+    # starts at or before the causal frontier of its last valid query
+    q_last = jnp.minimum(qi * bq + bq, ln) - 1
 
-    @pl.when(jnp.any(live))
+    @pl.when((qi * bq < ln) & (j * ps <= off + q_last))
     def _step():
-        q = q_ref[0, 0].astype(jnp.float32) * scale      # (bq * g, d)
-        k = k_ref[0, :, 0].astype(jnp.float32)           # (ps, d)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(live, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # rows with no live slot in the whole panel keep m == NEG_INF, so
-        # exp(s - m) would be exp(0) = 1 and poison them with a false
-        # uniform weighting; zero those terms so dead rows finish at l=0
-        # (-> zero output).  Live rows are untouched: their masked slots
-        # underflow to exactly 0 anyway.
-        p = jnp.where(live, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        # rows of the (bq * g, ps) score panel: row -> suffix-local q
+        # index (g adjacent rows share one query position), col -> slot
+        # in page j.  Mirrors models/layers.py::
+        # ragged_prefill_attention_mask: a slot participates when its
+        # logical position <= the query's absolute position (causal over
+        # prefix + own suffix) and the query is live.
+        qrel = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq * g, ps),
+                                                  0) // g
+        kv_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (bq * g, ps),
+                                                   1)
+        live = (kv_pos <= off + qrel) & (qrel < ln)
+        online_softmax_page(q_ref[0, 0].astype(jnp.float32) * scale,
+                            k_ref[0, 0], v_ref[0, 0], live,
+                            m_ref, l_ref, acc_ref)
 
     @pl.when(j == blocks - 1)
     def _finish():
@@ -112,7 +106,7 @@ def flash_prefill_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
                          offsets: jnp.ndarray, lens: jnp.ndarray, *,
                          interpret: bool = False,
                          block_q: int | None = None) -> jnp.ndarray:
-    """q: (R,S,H,D) suffix queries; k/v_pages: (P, page_size, KV, D) with
+    """q: (R,S,H,D) suffix queries; k/v_pages: (P, KV, page_size, D) with
     H % KV == 0; block_tables: (R, max_blocks) int32 (entries past a
     request's pages parked on the serving layer's scratch page);
     offsets/lens: (R,) int32 — absolute position of q[:, 0] (the shared
@@ -122,7 +116,7 @@ def flash_prefill_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
     (R,S,H,D); rows at or past ``lens`` are zero.
     """
     r, s, h, d = q.shape
-    n_pages, ps, kvh, _ = k_pages.shape
+    n_pages, kvh, ps, _ = k_pages.shape
     rt, blocks = block_tables.shape
     assert h % kvh == 0, (h, kvh)
     assert rt == r, (rt, r)
@@ -149,12 +143,12 @@ def flash_prefill_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
                 pl.BlockSpec((1, 1, bq * g, d),
                              lambda ri, kv, qi, j, bt, off, ln:
                              (ri, kv, qi, 0)),
-                pl.BlockSpec((1, ps, 1, d),
+                pl.BlockSpec((1, 1, ps, d),
                              lambda ri, kv, qi, j, bt, off, ln:
-                             (bt[ri, j], 0, kv, 0)),
-                pl.BlockSpec((1, ps, 1, d),
+                             (bt[ri, j], kv, 0, 0)),
+                pl.BlockSpec((1, 1, ps, d),
                              lambda ri, kv, qi, j, bt, off, ln:
-                             (bt[ri, j], 0, kv, 0)),
+                             (bt[ri, j], kv, 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, bq * g, d),
                                    lambda ri, kv, qi, j, bt, off, ln:

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.configs.registry import get_config
 from repro.data.synthetic import lm_tokens
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.api import build_model
 
@@ -129,6 +130,7 @@ def main(argv=None):
     ap.add_argument("--no-scan", action="store_true",
                     help="legacy per-token Python decode loop")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.enc_dec:

@@ -20,6 +20,7 @@ import jax
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.registry import get_config
 from repro.data.pipeline import ShardedBatcher, TokenSource
+from repro.launch.compile_cache import configure_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.api import build_model
 from repro.optim.optimizers import adamw, cosine_schedule
@@ -43,6 +44,7 @@ def main(argv=None):
                     help="comma-separated steps to fail at")
     ap.add_argument("--grad-compression", action="store_true")
     args = ap.parse_args(argv)
+    configure_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     dp, tp = (int(t) for t in args.mesh.split("x"))

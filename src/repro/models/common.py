@@ -49,16 +49,25 @@ DEFAULT_CTX = Ctx()
 
 
 # ------------------------------------------------------------------ init
+def normal_draw(key, shape) -> jnp.ndarray:
+    """Standard-normal float32 draw, kept a value of its own under jit.
+
+    ``jax.random.normal`` ends in a multiply by sqrt(2).  Inside one
+    compiled init program XLA would fold a caller's constant scale into
+    that factor — one rounding instead of two — and move weights by an
+    ulp against an eager init; the barrier keeps both roundings."""
+    return jax.lax.optimization_barrier(
+        jax.random.normal(key, shape, jnp.float32))
+
+
 def dense_init(key, d_in: int, d_out: int, dtype=jnp.float32,
                scale: float | None = None) -> jnp.ndarray:
     scale = scale if scale is not None else 1.0 / np.sqrt(d_in)
-    return (jax.random.normal(key, (d_in, d_out), jnp.float32) * scale
-            ).astype(dtype)
+    return (normal_draw(key, (d_in, d_out)) * scale).astype(dtype)
 
 
 def embed_init(key, vocab: int, d: int, dtype=jnp.float32) -> jnp.ndarray:
-    return (jax.random.normal(key, (vocab, d), jnp.float32) * 0.02
-            ).astype(dtype)
+    return (normal_draw(key, (vocab, d)) * 0.02).astype(dtype)
 
 
 # ------------------------------------------------------------- linear op

@@ -33,27 +33,6 @@ from repro.models.common import (Ctx, act_fn, apply_rope, dense_init,
                                  init_norm, linear, norm_apply)
 from repro.quant.policy import INT8, quantize_int8
 
-if hasattr(jax, "shard_map"):  # jax>=0.6
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # type: ignore
-
-# The replication-check kwarg was renamed check_rep -> check_vma across
-# JAX versions; feature-detect against the installed signature so the
-# _moe_ep shard_map works on either side of the rename.
-import inspect as _inspect
-
-_SHARD_MAP_CHECK_KW = next(
-    (kw for kw in ("check_vma", "check_rep")
-     if kw in _inspect.signature(shard_map).parameters), None)
-
-
-def _shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """shard_map with the replication/VMA check disabled, version-portably."""
-    kw = {_SHARD_MAP_CHECK_KW: False} if _SHARD_MAP_CHECK_KW else {}
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **kw)
-
 NEG_INF = -1e30
 
 
@@ -153,8 +132,9 @@ def paged_kv_positions(seq_lens: jnp.ndarray, n_slots: int) -> jnp.ndarray:
     ``i <= seq_lens[r]`` (the slot at ``seq_lens[r]`` is the current
     token, written before attention) and is empty (2**30) past it.
     ``seq_lens`` is per-request — rows are ragged by construction.  The
-    single source of truth shared by the jnp paged oracle and the
-    flash_decode_paged kernel's mask, so the two cannot drift.
+    jnp paged oracle masks with it; the flash_decode_paged kernel builds
+    the same predicate in-kernel from ``seq_lens`` (tests/test_paged.py
+    pins the two against each other).
     """
     slot = jnp.arange(n_slots)
     return jnp.where(slot[None, :] <= seq_lens[:, None], slot[None, :],
@@ -401,22 +381,24 @@ def _paged_attention_decode(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     """One-token attention over a paged block-table cache (ragged batch).
 
     q/k/v: (R, 1, ·, hd) — already roped with per-request positions.  The
-    per-layer cache carries the page pool (``k_pages``/``v_pages``:
-    (P, page_size, KV, hd)) plus the batch-shared ``block_tables`` (R, M)
-    and ``seq_lens`` (R,) injected by lm_apply.  The current token's K/V
-    is scattered into page ``block_tables[r, seq_lens[r] // ps]`` before
-    attention, then each request attends its own prefix — the jnp oracle
-    gathers pages through the block table, the Pallas kernel
-    (kernels/flash_decode_paged.py) dereferences it per grid step.  Both
-    consume the same paged_kv_positions/paged_decode_attention_mask, so
-    they cannot disagree about live slots.  Sliding-window ring layouts
-    are not paged (the serving engine gates on ``cfg.sliding_window``).
+    per-layer cache carries the page pool (``k_pages``/``v_pages`` in the
+    head-major layout serving/paged_cache.py owns) plus the batch-shared
+    ``block_tables`` (R, M) and ``seq_lens`` (R,) injected by lm_apply.
+    The current token's K/V is scattered into page
+    ``block_tables[r, seq_lens[r] // ps]`` before attention, then each
+    request attends its own prefix — the jnp oracle gathers pages through
+    the block table and masks with paged_decode_attention_mask, the
+    Pallas kernel (kernels/flash_decode_paged.py) dereferences the table
+    per grid step and builds the same predicate from ``seq_lens``.
+    Sliding-window ring layouts are not paged (the serving engine gates
+    on ``cfg.sliding_window``).
     """
+    from repro.serving.paged_cache import gather_request_kv, write_token_kv
     assert not cfg.sliding_window, \
         "paged decode supports linear caches only"
     kp, vp = cache["k_pages"], cache["v_pages"]
     bt, sl = cache["block_tables"], cache["seq_lens"]
-    _, ps, kvh, hd = kp.shape
+    ps = kp.shape[2]
     r, _, h, _ = q.shape
     blocks = bt.shape[1]
     n_slots = blocks * ps
@@ -426,23 +408,22 @@ def _paged_attention_decode(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     pos_w = jnp.minimum(sl, n_slots - 1)
     pidx = bt[jnp.arange(r), pos_w // ps]
     slot = pos_w % ps
-    kp = kp.at[pidx, slot].set(k[:, 0].astype(kp.dtype))
-    vp = vp.at[pidx, slot].set(v[:, 0].astype(vp.dtype))
+    kp = write_token_kv(kp, pidx, slot, k[:, 0])
+    vp = write_token_kv(vp, pidx, slot, v[:, 0])
     new_cache = dict(cache, k_pages=kp, v_pages=vp)
-    kv_pos = paged_kv_positions(sl, n_slots)
-    mask = paged_decode_attention_mask(kv_pos, sl)
     seq_sharded = (ctx.mesh is not None
                    and "model" in ctx.mesh.axis_names
                    and _axis_size(ctx.mesh, "model") > 1)
     if ctx.use_kernels and not seq_sharded:
         from repro.kernels.flash_decode_paged import flash_decode_paged
-        out = flash_decode_paged(q, kp, vp, bt, mask,
+        out = flash_decode_paged(q, kp, vp, bt, sl,
                                  interpret=ctx.interpret).astype(q.dtype)
     else:
         # jnp oracle: gather each request's pages into contiguous K/V
-        kf = kp[bt].reshape(r, n_slots, kvh, hd)
-        vf = vp[bt].reshape(r, n_slots, kvh, hd)
-        out = _masked_decode_attention(q, kf, vf, mask, h)
+        mask = paged_decode_attention_mask(paged_kv_positions(sl, n_slots),
+                                           sl)
+        out = _masked_decode_attention(q, gather_request_kv(kp, bt),
+                                       gather_request_kv(vp, bt), mask, h)
     return out, new_cache
 
 
@@ -470,12 +451,13 @@ def _paged_attention_prefill(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     scalar prefetch, mask semantics per
     :func:`ragged_prefill_attention_mask`.
     """
+    from repro.serving.paged_cache import gather_request_kv, write_token_kv
     assert not cfg.sliding_window, \
         "paged prefill supports linear caches only"
     kp, vp = cache["k_pages"], cache["v_pages"]
     bt, off = cache["block_tables"], cache["seq_lens"]
     lens = cache["prefill_lens"]
-    _, ps, kvh, hd = kp.shape
+    _, kvh, ps, hd = kp.shape
     r, s, h, _ = q.shape
     blocks = bt.shape[1]
     n_slots = blocks * ps
@@ -487,8 +469,8 @@ def _paged_attention_prefill(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     rows = jnp.arange(r)[:, None]
     pidx = jnp.where(valid, bt[rows, pos_c // ps], 0)
     slot = pos_c % ps
-    kp = kp.at[pidx, slot].set(k.astype(kp.dtype))
-    vp = vp.at[pidx, slot].set(v.astype(vp.dtype))
+    kp = write_token_kv(kp, pidx, slot, k)
+    vp = write_token_kv(vp, pidx, slot, v)
     new_cache = dict(cache, k_pages=kp, v_pages=vp)
     seq_sharded = (ctx.mesh is not None
                    and "model" in ctx.mesh.axis_names
@@ -506,8 +488,8 @@ def _paged_attention_prefill(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     else:
         # jnp oracle: gather each request's pages into contiguous K/V and
         # run the standard chunked-mea prefill with per-request positions
-        kf = kp[bt].reshape(r, n_slots, kvh, hd)
-        vf = vp[bt].reshape(r, n_slots, kvh, hd)
+        kf = gather_request_kv(kp, bt)
+        vf = gather_request_kv(vp, bt)
         out = mea_attention(q, _expand_kv(kf, h), _expand_kv(vf, h),
                             pos, jnp.arange(n_slots),
                             causal=True, chunk=cfg.attn_chunk,
@@ -927,10 +909,10 @@ def _moe_ep(ctx: Ctx, cfg: ArchConfig, p, x):
     n_loc_static = (b // max(1, dp)) * s
     body = ep_fn if (n_loc_static % tp == 0 and n_loc_static >= tp) \
         else ep_small_fn
-    fn = _shard_map_unchecked(body, mesh=mesh,
-                              in_specs=(x_spec, P(None, None), w_in_spec,
-                                        w_in_spec, wd_spec),
-                              out_specs=x_spec)
+    fn = jax.shard_map(body, mesh=mesh, check_vma=False,
+                       in_specs=(x_spec, P(None, None), w_in_spec,
+                                 w_in_spec, wd_spec),
+                       out_specs=x_spec)
     return fn(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
 
 

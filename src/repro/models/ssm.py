@@ -19,9 +19,10 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.models.common import Ctx, dense_init, linear, rmsnorm
+from repro.models.common import Ctx, dense_init, linear, normal_draw, rmsnorm
 
 NEG_INF = -1e30
 
@@ -43,10 +44,13 @@ def init_mamba2(key, cfg: ArchConfig):
     dt = cfg.pdt
     params: dict[str, Any] = {
         "in_proj": dense_init(ks[0], d, 2 * d_in + 2 * n + nh, dt),
-        "conv_w": (jax.random.normal(ks[1], (cfg.ssm_conv, conv_dim),
-                                     jnp.float32) * 0.1).astype(dt),
+        "conv_w": (normal_draw(ks[1], (cfg.ssm_conv, conv_dim))
+                   * 0.1).astype(dt),
         "conv_b": jnp.zeros((conv_dim,), dt),
-        "A_log": jnp.log(jnp.arange(1, nh + 1, dtype=jnp.float32)),
+        # a host-side constant: the same table eagerly or under jit,
+        # where XLA's fused log can differ from a lone log op by an ulp
+        "A_log": jnp.asarray(np.log(np.arange(1, nh + 1,
+                                              dtype=np.float32))),
         "D": jnp.ones((nh,), jnp.float32),
         "dt_bias": jnp.zeros((nh,), jnp.float32),
         "norm_scale": jnp.ones((d_in,), dt),
@@ -235,8 +239,8 @@ def init_mlstm(key, cfg: ArchConfig):
     params = {
         "w_up": dense_init(ks[0], d, d_in, dt),
         "w_gate": dense_init(ks[1], d, d_in, dt),
-        "conv_w": (jax.random.normal(ks[2], (cfg.ssm_conv, d_in),
-                                     jnp.float32) * 0.1).astype(dt),
+        "conv_w": (normal_draw(ks[2], (cfg.ssm_conv, d_in))
+                   * 0.1).astype(dt),
         "conv_b": jnp.zeros((d_in,), dt),
         "wq": dense_init(ks[3], d_in, d_in, dt),
         "wk": dense_init(ks[4], d_in, d_in, dt),
@@ -436,8 +440,11 @@ def init_slstm(key, cfg: ArchConfig):
     dt = cfg.pdt
     params = {
         "w_in": dense_init(ks[0], d, 4 * d, dt),
-        "r": (jax.random.normal(ks[1], (4, h, dh, dh), jnp.float32)
-              / math.sqrt(dh)).astype(dt),
+        # a runtime divisor: under jit XLA turns division by a constant
+        # into a multiply by its rounded reciprocal
+        "r": (normal_draw(ks[1], (4, h, dh, dh))
+              / jax.lax.optimization_barrier(
+                  jnp.float32(math.sqrt(dh)))).astype(dt),
         "b": jnp.concatenate(
             [jnp.zeros((2 * d,), jnp.float32),
              3.0 * jnp.ones((d,), jnp.float32),      # f-gate bias

@@ -49,9 +49,12 @@ def _remat(cfg: ArchConfig, fn):
 
 
 def _stack_init(key, n: int, init_fn):
-    """vmap an init over n layer keys; returns (stacked params, axes)."""
+    """Map an init over n layer keys; returns (stacked params, axes).
+
+    ``lax.map`` (a loop) rather than vmap: under jit a layer's float32
+    draws are then one layer's worth at a time, not the whole stack's."""
     keys = jax.random.split(key, n)
-    params = jax.vmap(lambda k: init_fn(k)[0])(keys)
+    params = jax.lax.map(lambda k: init_fn(k)[0], keys)
     _, axes = init_fn(key)
     axes = jax.tree.map(lambda ax: ("layers",) + tuple(ax), axes,
                         is_leaf=lambda x: isinstance(x, tuple))

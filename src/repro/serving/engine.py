@@ -62,7 +62,8 @@ from repro.serving.faults import (FaultPlan, InjectedFault, ProcessCrashed,
 from repro.serving.observe import Observability, render_summary
 from repro.serving.paged_cache import (AllocatorError, PagedCacheConfig,
                                        TRASH_PAGE, init_paged_cache,
-                                       supports_paging)
+                                       pages_from_contiguous, read_pages,
+                                       supports_paging, write_pages)
 from repro.serving.plan import ServingPlan
 from repro.serving.recovery import (EngineStalledError, RecoveryManager,
                                     RecoveryPolicy, diagnostic_snapshot)
@@ -126,21 +127,21 @@ class PagedServingEngine:
     # ------------------------------------------------------------ jitted
     def _prefill_impl(self, params, prompt):
         """prompt: (1, S).  Contiguous scratch cache rounded up to whole
-        pages so the K/V reshapes to (L, n_pages, page_size, KV, hd)."""
+        pages so the K/V cuts into a page image (paged_cache layout)."""
         s = prompt.shape[1]
-        cache_len = self.pcfg.pages_for(s) * self.pcfg.page_size
+        ps = self.pcfg.page_size
+        cache_len = self.pcfg.pages_for(s) * ps
         cache, _ = self.model.init_cache(1, cache_len, self.cache_dtype)
         logits, cache = self.model.prefill(params, {"tokens": prompt},
                                            cache)
         tok = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
-        n_layers, _, _, kv, hd = cache["k"].shape
-        shape = (n_layers, -1, self.pcfg.page_size, kv, hd)
-        return tok, cache["k"].reshape(shape), cache["v"].reshape(shape)
+        return (tok, pages_from_contiguous(cache["k"], ps),
+                pages_from_contiguous(cache["v"], ps))
 
     def _write_pages_impl(self, blocks, pk, pv, rows):
-        """Scatter page chunks (L, n, ps, KV, hd) into physical ``rows``."""
-        return {"k_pages": blocks["k_pages"].at[:, rows].set(pk),
-                "v_pages": blocks["v_pages"].at[:, rows].set(pv)}
+        """Scatter page images into physical ``rows``."""
+        return {"k_pages": write_pages(blocks["k_pages"], rows, pk),
+                "v_pages": write_pages(blocks["v_pages"], rows, pv)}
 
     def _admit_batch_impl(self, params, blocks, tokens, bt, offsets, lens,
                           cow_src, cow_dst):
@@ -158,8 +159,8 @@ class PagedServingEngine:
         # resident in the request's own copy before this dispatch's
         # scatter appends the remaining suffix to that copy.  No-CoW
         # slots copy scratch->scratch, which the trash page absorbs.
-        kp = kp.at[:, cow_dst].set(kp[:, cow_src])
-        vp = vp.at[:, cow_dst].set(vp[:, cow_src])
+        kp = write_pages(kp, cow_dst, read_pages(kp, cow_src))
+        vp = write_pages(vp, cow_dst, read_pages(vp, cow_src))
         cache = {"blocks": {"k_pages": kp, "v_pages": vp},
                  "block_tables": bt, "seq_lens": offsets,
                  "prefill_lens": lens}
@@ -353,8 +354,8 @@ class PagedServingEngine:
         fault sites below) is caught by the recovery layer's one-time
         verification before a restore of the image is ever planned."""
         idx = jnp.asarray(np.asarray(swap.pages, np.int32))
-        swap.host_k = np.asarray(cache["blocks"]["k_pages"][:, idx])
-        swap.host_v = np.asarray(cache["blocks"]["v_pages"][:, idx])
+        swap.host_k = np.asarray(read_pages(cache["blocks"]["k_pages"], idx))
+        swap.host_v = np.asarray(read_pages(cache["blocks"]["v_pages"], idx))
         swap.checksum = image_checksum(swap.host_k, swap.host_v)
         swap.verified = False
         if faults is not None:

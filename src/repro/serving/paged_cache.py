@@ -10,13 +10,21 @@ of ``page_size``-token pages, each request owns an ordered list of pages
 next admission reuses immediately.  That is the memory architecture the
 continuous-batching scheduler (serving/scheduler.py) allocates against.
 
-Layout per layer: ``k_pages``/``v_pages``: (n_pages, page_size, KV, hd),
-stacked over layers by :func:`init_paged_cache` exactly like the
-contiguous cache so lm_apply's layer scan carries it unchanged.  Physical
-page 0 is reserved as the *scratch page* (:data:`TRASH_PAGE`): empty or
-drained batch slots keep running inside a jitted decode segment, and
-their (masked, discarded) writes land there instead of corrupting pages
-the allocator may already have handed to another request.
+Layout per layer: ``k_pages``/``v_pages``: (n_pages, KV, page_size, hd),
+head-major, so one head's page is a contiguous ``(page_size, hd)`` tile —
+the block the paged Pallas kernels DMA per grid step, and a shape the TPU
+compiler accepts as a block (its last two dims are a multiple of
+(8, 128) or the whole array dim).  :func:`init_paged_cache` stacks the
+per-layer pools over layers exactly like the contiguous cache, so
+lm_apply's layer scan carries them unchanged.  This module is the one
+place that knows the layout: :func:`pool_shape` builds it, and every
+reader and writer of pages (the layers' token scatter and oracle gather,
+the engine's serial-prefill reshape, page writes and copy-on-write fork,
+the swap images) goes through the page helpers below.  Physical page 0 is
+reserved as the *scratch page* (:data:`TRASH_PAGE`): empty or drained
+batch slots keep running inside a jitted decode segment, and their
+(masked, discarded) writes land there instead of corrupting pages the
+allocator may already have handed to another request.
 
 The page size is an optimization knob like any tile size: small pages
 waste less pool memory on partial tails (internal fragmentation ~
@@ -509,8 +517,7 @@ def init_paged_cache(cfg: ArchConfig, pcfg: PagedCacheConfig,
         raise ValueError(f"{cfg.name}: family={cfg.family} "
                          f"window={cfg.sliding_window} mla={cfg.use_mla} "
                          f"does not support the paged decode path")
-    shape = (cfg.n_layers, pcfg.n_pages, pcfg.page_size,
-             cfg.n_kv_heads, cfg.hd)
+    shape = pool_shape(cfg, pcfg)
     cache = {
         "blocks": {"k_pages": jnp.zeros(shape, dtype),
                    "v_pages": jnp.zeros(shape, dtype)},
@@ -518,15 +525,57 @@ def init_paged_cache(cfg: ArchConfig, pcfg: PagedCacheConfig,
                                  TRASH_PAGE, jnp.int32),
         "seq_lens": jnp.zeros((pcfg.max_slots,), jnp.int32),
     }
+    pool_axes = ("layers", "kv_pages", "kv_heads", None, "head_dim")
     axes = {
-        "blocks": {"k_pages": ("layers", "kv_pages", None, "kv_heads",
-                               "head_dim"),
-                   "v_pages": ("layers", "kv_pages", None, "kv_heads",
-                               "head_dim")},
+        "blocks": {"k_pages": pool_axes, "v_pages": pool_axes},
         "block_tables": (None, None),
         "seq_lens": (None,),
     }
     return cache, axes
+
+
+# ------------------------------------------------------------ page layout
+def pool_shape(cfg: ArchConfig, pcfg: PagedCacheConfig) -> tuple:
+    """Whole-model page pool: (n_layers, n_pages, KV, page_size, hd)."""
+    return (cfg.n_layers, pcfg.n_pages, cfg.n_kv_heads, pcfg.page_size,
+            cfg.hd)
+
+
+def write_token_kv(pages, page_idx, slot, kv):
+    """Per-layer scatter of token K/V rows into a (P, KV, ps, hd) pool:
+    token ``i`` lands in page ``page_idx[i]`` at in-page slot
+    ``slot[i]``.  ``page_idx``/``slot`` share one index shape ``I`` and
+    ``kv`` is ``I + (KV, hd)``."""
+    return pages.at[page_idx, :, slot].set(kv.astype(pages.dtype))
+
+
+def gather_request_kv(pages, block_tables):
+    """Per-layer logical view for the jnp oracles: (R, M*ps, KV, hd), slot
+    ``j`` of request ``r`` being logical position ``j`` of its block
+    table ``block_tables`` (R, M)."""
+    r, m = block_tables.shape
+    _, kvh, ps, hd = pages.shape
+    g = pages[block_tables]                      # (R, M, KV, ps, hd)
+    return g.transpose(0, 1, 3, 2, 4).reshape(r, m * ps, kvh, hd)
+
+
+def read_pages(pool, pages):
+    """Whole-model image of physical ``pages``: (L, n, KV, ps, hd) — the
+    swap-image layout the host keeps and the journal spills."""
+    return pool[:, pages]
+
+
+def write_pages(pool, pages, image):
+    """Inverse of :func:`read_pages`: store an image into ``pages``."""
+    return pool.at[:, pages].set(image.astype(pool.dtype))
+
+
+def pages_from_contiguous(kv, page_size: int):
+    """A contiguous batch-1 cache (L, 1, n*page_size, KV, hd) cut into an
+    image of ``n`` whole pages (L, n, KV, page_size, hd)."""
+    n_layers, _, s, kvh, hd = kv.shape
+    return kv.reshape(n_layers, s // page_size, page_size, kvh,
+                      hd).transpose(0, 1, 3, 2, 4)
 
 
 def preferred_page_size(cfg: ArchConfig, pcfg_slots: int,

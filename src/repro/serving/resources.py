@@ -104,7 +104,7 @@ class SwapState:
     pages: list[int]
     n_tokens: int                       # cache tokens resident at preempt
     slot: int
-    host_k: Any = None                  # (L, len(pages), ps, KV, hd)
+    host_k: Any = None                  # paged_cache.read_pages image
     host_v: Any = None
     # Integrity: CRC of (host_k, host_v) recorded by the engine at
     # device_get time; the recovery layer verifies it once before the

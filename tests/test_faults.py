@@ -20,6 +20,9 @@ from repro.serving import (AllocatorError, ContinuousBatchingScheduler,
                            diagnostic_snapshot)
 from repro.serving.faults import corrupt_image, image_checksum
 
+# a one-page swap image of a head-major pool: (L, pages, KV, page_size, hd)
+_IMAGE = (2, 1, 1, 8, 8)
+
 
 # -------------------------------------------------------------- FaultPlan
 class TestFaultPlan:
@@ -165,7 +168,8 @@ class TestRecoveryManager:
         rec = RecoveryManager(RecoveryPolicy(backoff_segments=2), sched)
         restart, restore = _req(0), _req(1)
         restore.swap = SwapState(pages=[1], n_tokens=8, slot=0,
-                                 host_k=np.zeros(1), host_v=np.zeros(1))
+                                 host_k=np.zeros(_IMAGE),
+                                 host_v=np.zeros(_IMAGE))
         assert rec.hold(restart, "x", boundary=1, now=0.0)
         assert rec.hold(restore, "x", boundary=1, now=0.0)
         assert rec.restarts == 1               # only the image-less one
@@ -194,8 +198,8 @@ class TestRecoveryManager:
         sched = _sched()
         rec = RecoveryManager(RecoveryPolicy(), sched)
         good, corrupt, lost = _req(0), _req(1), _req(2)
-        for req, (k, v) in ((good, (np.ones(4), np.ones(4))),
-                            (corrupt, (np.ones(4), np.ones(4))),
+        for req, (k, v) in ((good, (np.ones(_IMAGE), np.ones(_IMAGE))),
+                            (corrupt, (np.ones(_IMAGE), np.ones(_IMAGE))),
                             (lost, (None, None))):
             req.swap = SwapState(pages=[1], n_tokens=8, slot=0,
                                  host_k=k, host_v=v)

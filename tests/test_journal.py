@@ -266,9 +266,17 @@ class TestReplayStateMachine:
 
     def test_image_save_load_round_trip_bfloat16(self, tmp_path):
         import ml_dtypes
+        from repro.configs.registry import get_config
+        from repro.serving.paged_cache import pool_shape, read_pages
         path = str(tmp_path / "img-00000000.npz")
-        k = np.arange(24, dtype=np.float32).reshape(2, 3, 4) \
+        # a swap image as the engine takes it: two pages of a head-major
+        # (L, P, KV, page_size, hd) pool
+        shape = pool_shape(get_config("qwen2_7b", smoke=True),
+                           PagedCacheConfig(page_size=4, n_pages=5))
+        pool = np.arange(np.prod(shape), dtype=np.float32).reshape(shape) \
             .astype(ml_dtypes.bfloat16)
+        k = np.asarray(read_pages(pool, np.array([3, 1])))
+        assert k.shape == (shape[0], 2) + shape[2:]
         v = -k
         _save_image(path, k, v)
         k2, v2 = _load_image(path)

@@ -22,6 +22,10 @@ from repro.serving import (ContinuousBatchingScheduler, PageAllocator,
                            PagedCacheConfig, PagedServingEngine,
                            PrefixCache, Request, TenantConfig,
                            TRASH_PAGE, init_paged_cache)
+from repro.serving.paged_cache import (gather_request_kv,
+                                       pages_from_contiguous, pool_shape,
+                                       read_pages, write_pages,
+                                       write_token_kv)
 
 KEY = jax.random.PRNGKey(0)
 
@@ -87,28 +91,27 @@ class TestPagedMaskHelpers:
 
 # ------------------------------------------------------ kernel vs oracle
 def _paged_problem(key, slots, h, kvh, d, page_size, blocks, seq_lens):
-    """Random pages + a scrambled block table + the shared mask."""
+    """Random pages + a scrambled block table + per-request lengths."""
     ks = jax.random.split(key, 4)
     n_pages = slots * blocks + 1
     q = jax.random.normal(ks[0], (slots, 1, h, d), jnp.float32)
-    kp = jax.random.normal(ks[1], (n_pages, page_size, kvh, d),
+    kp = jax.random.normal(ks[1], (n_pages, kvh, page_size, d),
                            jnp.float32)
-    vp = jax.random.normal(ks[2], (n_pages, page_size, kvh, d),
+    vp = jax.random.normal(ks[2], (n_pages, kvh, page_size, d),
                            jnp.float32)
     perm = jax.random.permutation(ks[3], n_pages - 1) + 1
     bt = perm[:slots * blocks].reshape(slots, blocks).astype(jnp.int32)
-    sl = jnp.asarray(seq_lens, jnp.int32)
-    mask = L.paged_decode_attention_mask(
-        L.paged_kv_positions(sl, blocks * page_size), sl)
-    return q, kp, vp, bt, mask
+    return q, kp, vp, bt, jnp.asarray(seq_lens, jnp.int32)
 
 
-def _oracle(q, kp, vp, bt, mask):
+def _oracle(q, kp, vp, bt, sl):
+    """Direct masked softmax over the shared mask helper."""
     slots, _, h, d = q.shape
-    _, ps, kvh, _ = kp.shape
-    blocks = bt.shape[1]
-    kf = kp[bt].reshape(slots, blocks * ps, kvh, d)
-    vf = vp[bt].reshape(slots, blocks * ps, kvh, d)
+    ps = kp.shape[2]
+    n = bt.shape[1] * ps
+    mask = L.paged_decode_attention_mask(L.paged_kv_positions(sl, n), sl)
+    kf = gather_request_kv(kp, bt)
+    vf = gather_request_kv(vp, bt)
     k_exp = L._expand_kv(kf, h)
     v_exp = L._expand_kv(vf, h)
     s = jnp.einsum("bqhd,bkhd->bhqk",
@@ -126,19 +129,19 @@ class TestPagedKernelVsOracle:
         slots, blocks, d = 3, 3, 8
         cap = blocks * page_size
         seq_lens = [0, cap // 2, cap - 1]          # empty-ish / mid / full
-        q, kp, vp, bt, mask = _paged_problem(
+        q, kp, vp, bt, sl = _paged_problem(
             KEY, slots, h, kvh, d, page_size, blocks, seq_lens)
-        out = flash_decode_paged(q, kp, vp, bt, mask, interpret=True)
-        ref = _oracle(q, kp, vp, bt, mask)
+        out = flash_decode_paged(q, kp, vp, bt, sl, interpret=True)
+        ref = _oracle(q, kp, vp, bt, sl)
         assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
     @pytest.mark.parametrize("seq_lens", [[0, 1, 2, 3], [5, 17, 9, 30]])
     def test_ragged_lengths(self, seq_lens):
         slots, blocks, ps, h, kvh, d = 4, 4, 8, 4, 2, 8
-        q, kp, vp, bt, mask = _paged_problem(
+        q, kp, vp, bt, sl = _paged_problem(
             jax.random.PRNGKey(7), slots, h, kvh, d, ps, blocks, seq_lens)
-        out = flash_decode_paged(q, kp, vp, bt, mask, interpret=True)
-        ref = _oracle(q, kp, vp, bt, mask)
+        out = flash_decode_paged(q, kp, vp, bt, sl, interpret=True)
+        ref = _oracle(q, kp, vp, bt, sl)
         assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
     def test_scrambled_vs_identity_block_table(self):
@@ -146,16 +149,123 @@ class TestPagedKernelVsOracle:
         K/V through a scrambled table equals an identity layout."""
         slots, blocks, ps, h, kvh, d = 2, 3, 4, 4, 2, 8
         seq_lens = [7, 11]
-        q, kp, vp, bt, mask = _paged_problem(
+        q, kp, vp, bt, sl = _paged_problem(
             jax.random.PRNGKey(3), slots, h, kvh, d, ps, blocks, seq_lens)
         ident_bt = 1 + jnp.arange(slots * blocks,
                                   dtype=jnp.int32).reshape(slots, blocks)
         kp_i = kp.at[ident_bt.reshape(-1)].set(kp[bt.reshape(-1)])
         vp_i = vp.at[ident_bt.reshape(-1)].set(vp[bt.reshape(-1)])
-        out_s = flash_decode_paged(q, kp, vp, bt, mask, interpret=True)
-        out_i = flash_decode_paged(q, kp_i, vp_i, ident_bt, mask,
+        out_s = flash_decode_paged(q, kp, vp, bt, sl, interpret=True)
+        out_i = flash_decode_paged(q, kp_i, vp_i, ident_bt, sl,
                                    interpret=True)
         assert float(jnp.max(jnp.abs(out_s - out_i))) < 1e-6
+
+
+def _slot_probe_pool(bt, n_pages, kvh, ps):
+    """K = 0 (every live slot scores alike) and V = one-hot of the logical
+    slot, scattered through the block table: the kernel's output row is
+    then 1/|live| on exactly the slots it attended."""
+    r, blocks = bt.shape
+    n = blocks * ps
+    logical = jnp.broadcast_to(jnp.eye(n, dtype=jnp.float32)[None, :, None],
+                               (r, n, kvh, n))
+    vp = jnp.zeros((n_pages, kvh, ps, n), jnp.float32)
+    vp = vp.at[bt.reshape(-1)].set(
+        logical.reshape(r, blocks, ps, kvh, n).transpose(0, 1, 3, 2, 4)
+        .reshape(r * blocks, kvh, ps, n))
+    return jnp.zeros_like(vp), vp
+
+
+class TestDecodePredicate:
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    @pytest.mark.parametrize("ps,blocks", [(4, 5), (8, 3), (16, 2)])
+    def test_in_kernel_predicate_equals_mask_helper(self, seed, ps, blocks):
+        """The kernel attends exactly the slots
+        paged_decode_attention_mask marks, across ragged lengths (empty,
+        page-aligned, full) and scrambled block tables."""
+        slots, h, kvh = 4, 4, 2
+        n = blocks * ps
+        key = jax.random.PRNGKey(seed)
+        lens = jax.random.randint(key, (slots,), 0, n)
+        sl = lens.at[0].set(0).at[1].set(ps - 1).at[2].set(n - 1)
+        n_pages = slots * blocks + 1
+        perm = jax.random.permutation(jax.random.fold_in(key, 1),
+                                      n_pages - 1) + 1
+        bt = perm[:slots * blocks].reshape(slots, blocks).astype(jnp.int32)
+        kp, vp = _slot_probe_pool(bt, n_pages, kvh, ps)
+        q = jax.random.normal(jax.random.fold_in(key, 2), (slots, 1, h, n))
+        out = flash_decode_paged(q, kp, vp, bt, sl, interpret=True)
+        mask = L.paged_decode_attention_mask(L.paged_kv_positions(sl, n), sl)
+        attended = np.asarray(out[:, 0] > 0)                  # (R, H, n)
+        want = np.broadcast_to(np.asarray(mask)[:, None], attended.shape)
+        assert np.array_equal(attended, want)
+
+    def test_pages_past_length_are_never_read(self):
+        """A page wholly past a request's length is skipped: NaN there
+        leaves the output finite and equal to the clean pool's."""
+        slots, blocks, ps, h, kvh, d = 3, 4, 8, 4, 2, 8
+        seq_lens = [3, 12, 0]
+        q, kp, vp, bt, sl = _paged_problem(
+            jax.random.PRNGKey(11), slots, h, kvh, d, ps, blocks, seq_lens)
+        dead = [int(bt[r, j]) for r in range(slots) for j in range(blocks)
+                if j * ps > seq_lens[r]]
+        assert dead
+        kp_nan = kp.at[jnp.asarray(dead)].set(jnp.nan)
+        vp_nan = vp.at[jnp.asarray(dead)].set(jnp.nan)
+        out = flash_decode_paged(q, kp_nan, vp_nan, bt, sl, interpret=True)
+        ref = flash_decode_paged(q, kp, vp, bt, sl, interpret=True)
+        assert bool(jnp.all(jnp.isfinite(out)))
+        assert float(jnp.max(jnp.abs(out - ref))) == 0.0
+
+
+class TestPoolLayout:
+    """The page-layout helpers every pool reader and writer goes
+    through, against explicit per-token loops."""
+
+    def test_pool_shape_is_head_major(self):
+        cfg = get_config("qwen2_7b", smoke=True)
+        pcfg = PagedCacheConfig(page_size=8, n_pages=5)
+        assert pool_shape(cfg, pcfg) == (cfg.n_layers, 5, cfg.n_kv_heads,
+                                         8, cfg.hd)
+        cache, _ = init_paged_cache(cfg, pcfg)
+        assert cache["blocks"]["k_pages"].shape == pool_shape(cfg, pcfg)
+
+    def test_token_scatter_and_request_gather(self):
+        n_pages, kvh, ps, hd = 6, 2, 4, 3
+        rng = np.random.default_rng(0)
+        bt = np.array([[3, 1], [5, 2]], np.int32)
+        pos = np.array([[0, 5, 7], [2, 3, 4]])              # (R, S)
+        kv = rng.normal(size=pos.shape + (kvh, hd)).astype(np.float32)
+        rows = np.arange(2)[:, None]
+        pages = write_token_kv(jnp.zeros((n_pages, kvh, ps, hd)),
+                               bt[rows, pos // ps], pos % ps, kv)
+        want = np.zeros((n_pages, kvh, ps, hd), np.float32)
+        for r in range(2):
+            for i, p in enumerate(pos[r]):
+                want[bt[r, p // ps], :, p % ps] = kv[r, i]
+        assert np.array_equal(np.asarray(pages), want)
+        got = np.asarray(gather_request_kv(pages, jnp.asarray(bt)))
+        assert got.shape == (2, 2 * ps, kvh, hd)
+        for r in range(2):
+            for i, p in enumerate(pos[r]):
+                assert np.array_equal(got[r, p], kv[r, i])
+
+    def test_page_images_round_trip(self):
+        n_layers, kvh, ps, hd = 2, 2, 4, 3
+        rng = np.random.default_rng(1)
+        contiguous = rng.normal(size=(n_layers, 1, 3 * ps, kvh,
+                                      hd)).astype(np.float32)
+        image = pages_from_contiguous(jnp.asarray(contiguous), ps)
+        assert image.shape == (n_layers, 3, kvh, ps, hd)
+        for b in range(3):
+            for s in range(ps):
+                assert np.array_equal(np.asarray(image[:, b, :, s]),
+                                      contiguous[:, 0, b * ps + s])
+        pool = jnp.zeros((n_layers, 7, kvh, ps, hd))
+        rows = jnp.asarray([4, 1, 6])
+        pool = write_pages(pool, rows, image)
+        assert np.array_equal(np.asarray(read_pages(pool, rows)),
+                              np.asarray(image))
 
 
 # ------------------------------------- engine: paged vs contiguous tokens
@@ -507,8 +617,8 @@ def _ragged_problem(key, slots, s, h, kvh, d, ps, blocks, offs, lens):
     ks = jax.random.split(key, 3)
     n_pages = slots * blocks + 1
     q = jax.random.normal(ks[0], (slots, s, h, d), jnp.float32)
-    kp = jax.random.normal(ks[1], (n_pages, ps, kvh, d), jnp.float32)
-    vp = jax.random.normal(ks[2], (n_pages, ps, kvh, d), jnp.float32)
+    kp = jax.random.normal(ks[1], (n_pages, kvh, ps, d), jnp.float32)
+    vp = jax.random.normal(ks[2], (n_pages, kvh, ps, d), jnp.float32)
     perm = jax.random.permutation(ks[2], n_pages - 1) + 1
     bt = perm[:slots * blocks].reshape(slots, blocks).astype(jnp.int32)
     return (q, kp, vp, bt, jnp.asarray(offs, jnp.int32),
@@ -519,10 +629,9 @@ def _ragged_oracle(q, kp, vp, bt, offs, lens):
     """Direct masked softmax over the shared mask helper — independent of
     both the kernel and the mea-based layer oracle."""
     r, s, h, d = q.shape
-    _, ps, kvh, _ = kp.shape
-    n = bt.shape[1] * ps
-    kf = L._expand_kv(kp[bt].reshape(r, n, kvh, d), h)
-    vf = L._expand_kv(vp[bt].reshape(r, n, kvh, d), h)
+    n = bt.shape[1] * kp.shape[2]
+    kf = L._expand_kv(gather_request_kv(kp, bt), h)
+    vf = L._expand_kv(gather_request_kv(vp, bt), h)
     mask = L.ragged_prefill_attention_mask(offs, lens, s, n)
     sgl = jnp.einsum("bqhd,bkhd->bhqk",
                      q.astype(jnp.float32) / math.sqrt(d),
@@ -742,10 +851,10 @@ class TestBatchedPrefillBitIdentical:
             for bi in range(pcfg.pages_for(pl)):
                 valid = min(ps, pl - bi * ps)
                 pg = pgs[bi]
-                assert np.array_equal(ks[:, pg, :valid],
-                                      kb[:, pg, :valid]), (rid, bi)
-                assert np.array_equal(vs[:, pg, :valid],
-                                      vb[:, pg, :valid]), (rid, bi)
+                assert np.array_equal(ks[:, pg, :, :valid],
+                                      kb[:, pg, :, :valid]), (rid, bi)
+                assert np.array_equal(vs[:, pg, :, :valid],
+                                      vb[:, pg, :, :valid]), (rid, bi)
 
 
 class TestRaggedPrefillAutotune:
