@@ -1,0 +1,8 @@
+"""Chip benchmark of the paged serving path.
+
+One run serves one cell of ``BENCHMARK.json`` (a model configuration under
+a traffic mix) on one TPU and prints the cell's metrics; see ``run.py``.
+Everything a cell is made of is a file found by name: the configuration
+under ``configs/``, the traffic mix under ``traffic/``, and each per-layer
+metric's reader under ``metrics/``.
+"""
