@@ -408,8 +408,9 @@ def _paged_attention_decode(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     pos_w = jnp.minimum(sl, n_slots - 1)
     pidx = bt[jnp.arange(r), pos_w // ps]
     slot = pos_w % ps
-    kp = write_token_kv(kp, pidx, slot, k[:, 0])
-    vp = write_token_kv(vp, pidx, slot, v[:, 0])
+    with jax.named_scope("kv_write"):
+        kp = write_token_kv(kp, pidx, slot, k[:, 0])
+        vp = write_token_kv(vp, pidx, slot, v[:, 0])
     new_cache = dict(cache, k_pages=kp, v_pages=vp)
     seq_sharded = (ctx.mesh is not None
                    and "model" in ctx.mesh.axis_names
@@ -469,8 +470,9 @@ def _paged_attention_prefill(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     rows = jnp.arange(r)[:, None]
     pidx = jnp.where(valid, bt[rows, pos_c // ps], 0)
     slot = pos_c % ps
-    kp = write_token_kv(kp, pidx, slot, k)
-    vp = write_token_kv(vp, pidx, slot, v)
+    with jax.named_scope("kv_write"):
+        kp = write_token_kv(kp, pidx, slot, k)
+        vp = write_token_kv(vp, pidx, slot, v)
     new_cache = dict(cache, k_pages=kp, v_pages=vp)
     seq_sharded = (ctx.mesh is not None
                    and "model" in ctx.mesh.axis_names

@@ -81,17 +81,22 @@ def init_lm_block(key, cfg: ArchConfig):
 
 
 def lm_block_apply(ctx: Ctx, cfg: ArchConfig, p, x, positions, cache):
-    h = norm_apply(cfg.norm, p["attn_norm"], x)
-    if cfg.use_mla:
-        a, cache = L.mla_attention(ctx, cfg, p["attn"], h, positions, cache)
-    else:
-        a, cache = L.attention(ctx, cfg, p["attn"], h, positions, cache)
+    # the named scopes tag the ops of each part in the HLO metadata
+    # (op_name), which is what a profiler trace groups device time by
+    with jax.named_scope("attention"):
+        h = norm_apply(cfg.norm, p["attn_norm"], x)
+        if cfg.use_mla:
+            a, cache = L.mla_attention(ctx, cfg, p["attn"], h, positions,
+                                       cache)
+        else:
+            a, cache = L.attention(ctx, cfg, p["attn"], h, positions, cache)
     x = x + a
-    h = norm_apply(cfg.norm, p["mlp_norm"], x)
-    if cfg.is_moe:
-        f = L.moe_ffn(ctx, cfg, p["moe"], h)
-    else:
-        f = L.mlp(ctx, cfg, p["mlp"], h)
+    with jax.named_scope("mlp"):
+        h = norm_apply(cfg.norm, p["mlp_norm"], x)
+        if cfg.is_moe:
+            f = L.moe_ffn(ctx, cfg, p["moe"], h)
+        else:
+            f = L.mlp(ctx, cfg, p["mlp"], h)
     x = x + f
     return shard_hidden(ctx, x), cache
 
@@ -280,9 +285,10 @@ def lm_apply(ctx: Ctx, cfg: ArchConfig, params, tokens, positions=None,
         else:
             new_cache = new_scan_cache
 
-    x = norm_apply(cfg.norm, params["final_norm"], x)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = linear(ctx, "lm_head", x, head)
+    with jax.named_scope("lm_head"):
+        x = norm_apply(cfg.norm, params["final_norm"], x)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = linear(ctx, "lm_head", x, head)
     return logits, new_cache
 
 

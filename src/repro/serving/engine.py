@@ -59,7 +59,7 @@ import numpy as np
 
 from repro.serving.faults import (FaultPlan, InjectedFault, ProcessCrashed,
                                   corrupt_image, image_checksum)
-from repro.serving.observe import Observability, render_summary
+from repro.serving.observe import Observability, render_summary, span
 from repro.serving.paged_cache import (AllocatorError, PagedCacheConfig,
                                        TRASH_PAGE, init_paged_cache,
                                        pages_from_contiguous, read_pages,
@@ -159,16 +159,18 @@ class PagedServingEngine:
         # resident in the request's own copy before this dispatch's
         # scatter appends the remaining suffix to that copy.  No-CoW
         # slots copy scratch->scratch, which the trash page absorbs.
-        kp = write_pages(kp, cow_dst, read_pages(kp, cow_src))
-        vp = write_pages(vp, cow_dst, read_pages(vp, cow_src))
+        with jax.named_scope("kv_write"):
+            kp = write_pages(kp, cow_dst, read_pages(kp, cow_src))
+            vp = write_pages(vp, cow_dst, read_pages(vp, cow_src))
         cache = {"blocks": {"k_pages": kp, "v_pages": vp},
                  "block_tables": bt, "seq_lens": offsets,
                  "prefill_lens": lens}
         logits, cache = self.model.decode_step(params, cache, tokens)
-        last = jnp.maximum(lens - 1, 0)
-        sel = jnp.take_along_axis(
-            logits, last[:, None, None], axis=1)[:, 0]       # (R, V)
-        tok = jnp.argmax(sel, axis=-1).astype(jnp.int32)
+        with jax.named_scope("sample"):
+            last = jnp.maximum(lens - 1, 0)
+            sel = jnp.take_along_axis(
+                logits, last[:, None, None], axis=1)[:, 0]   # (R, V)
+            tok = jnp.argmax(sel, axis=-1).astype(jnp.int32)
         return tok[:, None], cache["blocks"]
 
     def _segment_impl(self, params, cache, tok, active, n_gen, max_new,
@@ -193,11 +195,12 @@ class PagedServingEngine:
         def step(carry, _):
             cache, tok, active, n_gen, poison, poisoned = carry
             logits, cache = self.model.decode_step(params, cache, tok)
-            logits = logits + poison.astype(logits.dtype)[:, None, None]
-            bad = ~jnp.all(jnp.isfinite(logits[:, -1]), axis=-1)
+            with jax.named_scope("sample"):
+                logits = logits + poison.astype(logits.dtype)[:, None, None]
+                bad = ~jnp.all(jnp.isfinite(logits[:, -1]), axis=-1)
+                nxt = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
             poisoned = poisoned | bad
             ok = active & ~poisoned
-            nxt = jnp.argmax(logits[:, -1:], axis=-1).astype(jnp.int32)
             nxt = jnp.where(ok[:, None], nxt, 0)
             emitted = ok
             live = ok.astype(jnp.int32)
@@ -316,14 +319,27 @@ class PagedServingEngine:
     def _dispatch_admissions(self, cache, bt, reqs, s_pad, params):
         """One compact jitted dispatch for ``reqs`` at suffix pad
         ``s_pad``; returns ({slot: first token}, cache)."""
-        pcfg = self.pcfg
+        with span("serving.admit", rows=len(reqs), bucket=s_pad):
+            with span("serving.admit.inputs"):
+                inputs = self._admission_inputs(bt, reqs, s_pad)
+            with span("serving.admit.wait"):
+                tok1, blocks = self._admit_batch(params, cache["blocks"],
+                                                 *inputs)
+                tok1 = np.asarray(tok1)
+        return ({req.slot: int(tok1[i, 0]) for i, req in enumerate(reqs)},
+                dict(cache, blocks=blocks))
+
+    def _admission_inputs(self, bt, reqs, s_pad) -> tuple:
+        """The device inputs of one admission dispatch after ``params``
+        and the pools: suffix tokens, block tables, offsets, lengths and
+        copy-on-write pairs, rows padded to a power of two."""
         a = 1
         while a < len(reqs):
             a *= 2
         tokens = np.zeros((a, s_pad), np.int32)
         offs = np.zeros((a,), np.int32)
         lens = np.zeros((a,), np.int32)
-        gbt = np.full((a, pcfg.max_blocks), TRASH_PAGE, np.int32)
+        gbt = np.full((a, self.pcfg.max_blocks), TRASH_PAGE, np.int32)
         cow_src = np.full((a,), TRASH_PAGE, np.int32)
         cow_dst = np.full((a,), TRASH_PAGE, np.int32)
         for i, req in enumerate(reqs):
@@ -335,13 +351,8 @@ class PagedServingEngine:
             if req.cow_src is not None:
                 cow_src[i] = req.cow_src
                 cow_dst[i] = req.cow_dst
-        tok1, blocks = self._admit_batch(
-            params, cache["blocks"], jnp.asarray(tokens),
-            jnp.asarray(gbt), jnp.asarray(offs), jnp.asarray(lens),
-            jnp.asarray(cow_src), jnp.asarray(cow_dst))
-        tok1 = np.asarray(tok1)
-        return ({req.slot: int(tok1[i, 0]) for i, req in enumerate(reqs)},
-                dict(cache, blocks=blocks))
+        return tuple(jnp.asarray(x) for x in
+                     (tokens, gbt, offs, lens, cow_src, cow_dst))
 
     def _swap_out(self, cache, swap, faults=None) -> None:
         """Pull a preempted request's pages back to host memory.  Must
@@ -532,6 +543,13 @@ class EngineRun:
         self._g_queued = obs.gauge(
             "serving_queued_requests",
             "pending + preempted across tenants", rep)
+        # decode occupancy = tokens / row steps (always live)
+        self._c_row_steps = obs.counter(
+            "serving_decode_row_steps_total",
+            "segment_len x max_slots per segment run", rep)
+        self._c_tokens = obs.counter(
+            "serving_decode_tokens_total",
+            "tokens emitted by segment runs", rep)
         self.request_records: list[dict] = []
         # the write-ahead journal (serving/journal.py), when durability
         # is on: lifecycle records are emitted inside the boundary
@@ -553,7 +571,6 @@ class EngineRun:
         self.n_segments = 0
         self.n_prefill_dispatches = 0
         self.n_restore_dispatches = 0
-        self.prefill_s = 0.0
         self.decode_s = 0.0
         if clock is None:
             t0 = time.perf_counter()
@@ -654,7 +671,6 @@ class EngineRun:
         self.max_new[slot] = req.max_new_tokens
         self.active[slot] = req.max_new_tokens > 1
         req.tokens = [int(first_tok)]
-        req.t_admitted = now
         if req.t_first is None:
             req.t_first = now
 
@@ -736,58 +752,75 @@ class EngineRun:
         caller decides whether idleness means sleep (arrivals coming),
         a watchdog tick (:meth:`note_stall` — queued work that cannot
         admit), or that the run is simply drained.
+
+        Each phase runs inside a profiler span (``serving.*``, see
+        docs/observability.md), all of them inside ``serving.step``
+        whose ``boundary`` arg is the boundary index the tracer's
+        request events carry.
         """
-        engine, sched, rec = self.engine, self.sched, self.rec
-        faults, clock = self.faults, self.clock
-        bt, seq_lens = self.bt, self.seq_lens
         # the process_crash site: probed only when a journal is armed —
         # without one a process death is unrecoverable and injecting it
         # would only prove the obvious.  The journal drops its unflushed
         # buffer (kill -9: only fsync'd records survive) and the
         # exception escapes run() entirely; RestartRecovery is the only
         # way back.
-        if self.journal is not None and faults is not None \
-                and faults.should_fire("process_crash"):
+        if self.journal is not None and self.faults is not None \
+                and self.faults.should_fire("process_crash"):
             self.journal.crash()
             raise ProcessCrashed(self.boundary + 1)
         self.boundary += 1
-        boundary = self.boundary
+        with span("serving.step", boundary=self.boundary):
+            return self._step(self.boundary)
+
+    def _step(self, boundary: int) -> str:
+        engine, sched, rec = self.engine, self.sched, self.rec
+        faults, clock = self.faults, self.clock
+        bt, seq_lens = self.bt, self.seq_lens
         # recovery preflight: quarantined requests whose backoff
         # expired rejoin their tenant queues; queued host images are
         # checksum-verified exactly once (a corrupted/lost image
         # becomes a restart *before* its restore is planned); under
         # sustained pressure, stale queued work is shed (opt-in)
-        rec.release_due(boundary, clock())
-        rec.verify_swaps(boundary, clock())
-        rec.shed_stalled(boundary, clock())
-        # growth-on-demand: back the next segment's writes, possibly
-        # preempting victims...
-        preempted = sched.plan_growth()
-        # ...whose pages must reach host memory before any dispatch
-        # below can recycle them (their refs are already dropped)
-        for req in preempted:
-            engine._swap_out(self.cache, req.swap, faults)
-            self._park_slot(req.swap.slot)
-            if self.journal is not None:
-                # spill the host image beside the journal: a crash from
-                # here on restores this request through the verified-
-                # swap-image lane instead of restarting it
-                self.journal.spill_image(req)
+        with span("serving.preflight"):
+            rec.release_due(boundary, clock())
+            rec.verify_swaps(boundary, clock())
+            rec.shed_stalled(boundary, clock())
+        with span("serving.growth"):
+            # growth-on-demand: back the next segment's writes, possibly
+            # preempting victims...
+            preempted = sched.plan_growth()
+            # ...whose pages must reach host memory before any dispatch
+            # below can recycle them (their refs are already dropped)
+            for req in preempted:
+                engine._swap_out(self.cache, req.swap, faults)
+                self._park_slot(req.swap.slot)
+                if self.journal is not None:
+                    # spill the host image beside the journal: a crash
+                    # from here on restores this request through the
+                    # verified-swap-image lane instead of restarting it
+                    self.journal.spill_image(req)
+                if self.tracer is not None:
+                    self.tracer.event(req.rid, "PREEMPT", boundary,
+                                      clock(), by=req.preempted_by,
+                                      pages=len(req.swap.pages),
+                                      n_preempted=req.n_preempted)
+            # grown block tables: new pages append to the owned prefix
+            for slot, req in sched.running.items():
+                bt[slot, :len(req.pages)] = req.pages
             if self.tracer is not None:
-                self.tracer.event(req.rid, "PREEMPT", boundary, clock(),
-                                  by=req.preempted_by,
-                                  pages=len(req.swap.pages),
-                                  n_preempted=req.n_preempted)
-        # grown block tables: new pages append to the owned prefix
-        for slot, req in sched.running.items():
-            bt[slot, :len(req.pages)] = req.pages
-        if self.tracer is not None:
-            for slot in sorted(sched.running):
-                if sched.running[slot].stalled:
-                    self.tracer.event(sched.running[slot].rid, "STALL",
-                                      boundary, clock())
-        admitted = sched.try_admit()
-        rec.note_admitted(admitted)
+                for slot in sorted(sched.running):
+                    if sched.running[slot].stalled:
+                        self.tracer.event(sched.running[slot].rid,
+                                          "STALL", boundary, clock())
+        with span("serving.admit.plan"):
+            admitted = sched.try_admit()
+            rec.note_admitted(admitted)
+            # queue wait ends at the first admission; a restore or a
+            # retry after a fault keeps the first stamp
+            now = clock()
+            for req in admitted:
+                if req.t_admitted is None:
+                    req.t_admitted = now
         fresh = [r for r in admitted if r.swap is None]
         restored = [r for r in admitted if r.swap is not None]
         failed_admissions: list = []
@@ -802,26 +835,27 @@ class EngineRun:
             # The reverse order is safe — a restore reads nothing at
             # scatter time; its aliased pages are only attended at
             # the next segment, after every boundary dispatch.
-            for req in restored:
-                if restore_fault:
-                    failed_admissions.append(("restore", req))
-                    continue
-                try:
-                    if faults is not None:
-                        faults.gate("dispatch_restore")
-                    self.cache, n_disp = engine._restore(self.cache, bt,
-                                                         req)
-                except InjectedFault:
-                    restore_fault = True
-                    failed_admissions.append(("restore", req))
-                    continue
-                self.n_restore_dispatches += n_disp
-                slot = req.slot
-                seq_lens[slot] = req.swap.n_tokens
-                self.tok[slot] = req.tokens[-1]
-                self.n_gen[slot] = len(req.tokens)
-                self.max_new[slot] = req.max_new_tokens
-                ok_admitted.append(req)
+            with span("serving.restore"):
+                for req in restored:
+                    if restore_fault:
+                        failed_admissions.append(("restore", req))
+                        continue
+                    try:
+                        if faults is not None:
+                            faults.gate("dispatch_restore")
+                        self.cache, n_disp = engine._restore(self.cache,
+                                                             bt, req)
+                    except InjectedFault:
+                        restore_fault = True
+                        failed_admissions.append(("restore", req))
+                        continue
+                    self.n_restore_dispatches += n_disp
+                    slot = req.slot
+                    seq_lens[slot] = req.swap.n_tokens
+                    self.tok[slot] = req.tokens[-1]
+                    self.n_gen[slot] = len(req.tokens)
+                    self.max_new[slot] = req.max_new_tokens
+                    ok_admitted.append(req)
             if restore_fault:
                 # conservative: a fresh admission may prefix-share a
                 # page in the failed restore's range — without the
@@ -878,9 +912,8 @@ class EngineRun:
             sched.finish_boundary(ok_admitted)
             for kind, req in failed_admissions:
                 self._unwind_admission(kind, req)
-            dt_pf = time.perf_counter() - t_pf
-            self.prefill_s += dt_pf
-            self._h_admit.observe(dt_pf, (self._rep,))
+            self._h_admit.observe(time.perf_counter() - t_pf,
+                                  (self._rep,))
         self._retire_finished(clock())
         if not sched.running:
             return "idle"
@@ -905,15 +938,16 @@ class EngineRun:
                          boundary, now2, site="invariant")
             if not sched.running:
                 return "idle"
-        # the boundary checkpoint: everything committed as of this
-        # instant is exactly what the device pages back — the
-        # watermark every later rollback truncates to
-        rec.checkpoint(sched.running.values())
-        if self.journal is not None:
-            # the durable twin of rec.checkpoint: committed-token
-            # watermarks, batched one record per boundary and fsync'd on
-            # the plan's cadence
-            self.journal.checkpoint(boundary, sched.running.values())
+        with span("serving.checkpoint"):
+            # the boundary checkpoint: everything committed as of this
+            # instant is exactly what the device pages back — the
+            # watermark every later rollback truncates to
+            rec.checkpoint(sched.running.values())
+            if self.journal is not None:
+                # the durable twin of rec.checkpoint: committed-token
+                # watermarks, batched one record per boundary and
+                # fsync'd on the plan's cadence
+                self.journal.checkpoint(boundary, sched.running.values())
         # activity is a pure function of scheduler state: stalled
         # slots sit a segment out (their frozen write slot stays
         # inside pages they own), everyone else runs to max_new.
@@ -943,67 +977,75 @@ class EngineRun:
             rec._c_dispatch_faults.inc(1.0, (rec._rep,))
             return "skipped"
         t_seg = time.perf_counter()
-        cache = dict(self.cache, block_tables=jnp.asarray(bt),
-                     seq_lens=jnp.asarray(seq_lens))
-        cache, tok_d, act_d, gen_d, toks, emits, pois_d = \
-            engine._segment(self.params, cache, jnp.asarray(self.tok),
-                            jnp.asarray(self.active),
-                            jnp.asarray(self.n_gen),
-                            jnp.asarray(self.max_new),
-                            jnp.asarray(poison))
+        with span("serving.segment.inputs"):
+            cache = dict(self.cache, block_tables=jnp.asarray(bt),
+                         seq_lens=jnp.asarray(seq_lens))
+            inputs = tuple(jnp.asarray(x) for x in (
+                self.tok, self.active, self.n_gen, self.max_new, poison))
+        with span("serving.segment.wait"):
+            cache, tok_d, act_d, gen_d, toks, emits, pois_d = \
+                engine._segment(self.params, cache, *inputs)
+            toks = np.asarray(toks)
         self.cache = cache
         self.n_segments += 1
-        toks = np.asarray(toks)
         dt_seg = time.perf_counter() - t_seg
         self.decode_s += dt_seg
         emits = np.asarray(emits)
         n_emitted = int(emits.sum())
+        lab = (self._rep,)
+        steps, rows = self.pcfg.segment_len, self.pcfg.max_slots
+        self._c_row_steps.inc(steps * rows, lab)
+        self._c_tokens.inc(n_emitted, lab)
         if n_emitted:
-            self._h_decode.observe(dt_seg / n_emitted, (self._rep,))
-        # np.array (copy): host bookkeeping mutates these in place
-        self.tok = np.array(tok_d)
-        self.active = np.array(act_d)
-        self.n_gen = np.array(gen_d)
-        self.seq_lens = seq_lens = np.array(cache["seq_lens"])
-        poisoned = np.asarray(pois_d)
-        for slot, req in sched.running.items():
-            req.tokens.extend(
-                int(t) for t in toks[emits[:, slot], slot])
-        if self.tracer is not None:
-            for slot in sorted(sched.running):
-                n_em = int(emits[:, slot].sum())
-                if n_em:
-                    self.tracer.event(sched.running[slot].rid, "SEGMENT",
-                                      boundary, clock(), tokens=n_em)
-        # anti-livelock: surviving one generated segment makes a
-        # request preemptable again
-        sched.end_segment(slot for slot in sched.running
-                          if emits[:, slot].any())
-        # NaN/inf logit guard, before retirement: a poisoned slot
-        # stopped emitting in-graph and must never retire garbage —
-        # it rolls back to this boundary's checkpoint and retries
-        for slot in [s for s in list(sched.running) if poisoned[s]]:
-            self._quarantine_running(sched.running[slot],
-                                     "non-finite decode logits",
-                                     site="decode_poison")
-        if emits.any() or admitted or preempted:
-            self.no_progress = 0
-        else:
-            # unreachable by the liveness argument in resources.py
-            # (a stall implies an unprotected victim exists, and
-            # protected requests are freshly provisioned to run) —
-            # fail loudly rather than spin if a policy bug lands
-            self.note_stall()
-        self._retire_finished(clock())
-        if self.obs.enabled:
-            alloc = sched.allocator
-            lab = (self._rep,)
-            self._g_free.set(alloc.n_free, lab)
-            self._g_held.set(alloc.n_held, lab)
-            self._g_running.set(len(sched.running), lab)
-            self._g_queued.set(
-                sum(len(st.pending) + len(st.preempted)
-                    for st in sched.rm._tenants.values()), lab)
+            self._h_decode.observe(dt_seg / n_emitted, lab)
+        # the commit span carries the counters' increments, so a trace
+        # alone gives the window's decode occupancy
+        with span("serving.commit", tokens=n_emitted, steps=steps,
+                  rows=rows):
+            # np.array (copy): host bookkeeping mutates these in place
+            self.tok = np.array(tok_d)
+            self.active = np.array(act_d)
+            self.n_gen = np.array(gen_d)
+            self.seq_lens = np.array(cache["seq_lens"])
+            poisoned = np.asarray(pois_d)
+            for slot, req in sched.running.items():
+                req.tokens.extend(
+                    int(t) for t in toks[emits[:, slot], slot])
+            if self.tracer is not None:
+                for slot in sorted(sched.running):
+                    n_em = int(emits[:, slot].sum())
+                    if n_em:
+                        self.tracer.event(sched.running[slot].rid,
+                                          "SEGMENT", boundary, clock(),
+                                          tokens=n_em)
+            # anti-livelock: surviving one generated segment makes a
+            # request preemptable again
+            sched.end_segment(slot for slot in sched.running
+                              if emits[:, slot].any())
+            # NaN/inf logit guard, before retirement: a poisoned slot
+            # stopped emitting in-graph and must never retire garbage —
+            # it rolls back to this boundary's checkpoint and retries
+            for slot in [s for s in list(sched.running) if poisoned[s]]:
+                self._quarantine_running(sched.running[slot],
+                                         "non-finite decode logits",
+                                         site="decode_poison")
+            if emits.any() or admitted or preempted:
+                self.no_progress = 0
+            else:
+                # unreachable by the liveness argument in resources.py
+                # (a stall implies an unprotected victim exists, and
+                # protected requests are freshly provisioned to run) —
+                # fail loudly rather than spin if a policy bug lands
+                self.note_stall()
+            self._retire_finished(clock())
+            if self.obs.enabled:
+                alloc = sched.allocator
+                self._g_free.set(alloc.n_free, lab)
+                self._g_held.set(alloc.n_held, lab)
+                self._g_running.set(len(sched.running), lab)
+                self._g_queued.set(
+                    sum(len(st.pending) + len(st.preempted)
+                        for st in sched.rm._tenants.values()), lab)
         return "ran"
 
     # ------------------------------------------------------------- drain
@@ -1037,7 +1079,6 @@ class EngineRun:
                "n_dead_lettered": len(self.rec.dead),
                "n_prefill_dispatches": self.n_prefill_dispatches,
                "n_restore_dispatches": self.n_restore_dispatches,
-               "prefill_s": self.prefill_s,   # summed admission work
                "decode_s": self.decode_s,     # summed segment dispatches
                "wall_s": self.clock(),
                "recovery": self.rec.stats(),

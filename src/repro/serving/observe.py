@@ -1,6 +1,7 @@
-"""Serving observability: metrics registry, request tracing, exporters.
+"""Serving observability: metrics registry, request tracing, profiler
+spans, exporters.
 
-Zero-dependency telemetry substrate for the serving stack — the one
+Telemetry substrate for the serving stack (JAX its only dependency) — the one
 measurement path shared by benches, the SERVE replay scorer, and the
 per-module ``stats()`` views:
 
@@ -18,6 +19,10 @@ per-module ``stats()`` views:
   boundary index and the injectable-clock timestamp; ``sequence()``
   drops the timestamps, so traces from seeded ``FaultPlan`` runs are
   bit-reproducible modulo wall-clock.
+- **span** — a profiler span (``jax.profiler.TraceAnnotation``) around
+  one phase of the engine's boundary loop.  It lands on the host plane
+  of ``jax.profiler``'s own trace, on the device trace's clock, and
+  costs one object construction when no profiler is recording.
 - **Observability** — the facade the engine/cluster/scheduler thread
   through.  Counters are *always* live (they back the ``stats()`` thin
   views even when telemetry is off); histograms, gauges, the tracer,
@@ -37,10 +42,12 @@ import bisect
 import json
 import os
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "NULL_METRIC",
     "Observability", "SpanEvent", "Tracer", "exponential_buckets",
-    "render_summary",
+    "render_summary", "span",
 ]
 
 
@@ -473,6 +480,12 @@ class Tracer:
                 f.write(json.dumps(e.record(), sort_keys=True,
                                    separators=(",", ":")) + "\n")
         return path
+
+
+def span(name: str, **args) -> TraceAnnotation:
+    """A profiler span named ``name`` (``serving.*``) with ``args`` as its
+    stats; a context manager.  Nested spans nest in the trace."""
+    return TraceAnnotation(name, **args)
 
 
 # -------------------------------------------------------------- facade
