@@ -232,7 +232,8 @@ def check_kernels(cfg, plan, tile, seed: int, interpret: bool) -> dict:
     q1 = jax.random.normal(ks[3], (SLOTS, 1, h, d), jnp.bfloat16)
     mask = L.paged_decode_attention_mask(L.paged_kv_positions(sl, n), sl)
     kpn, vpn = poisoned(sl)
-    got = flash_decode_paged(q1, kpn, vpn, bt, sl, interpret=interpret)
+    got = flash_decode_paged(q1, kpn[None], vpn[None], bt, sl, 0,
+                             interpret=interpret)
     dec_err = _rel_err(got, _attend_f32(q1, kf, vf, mask[:, None]))
 
     s = MAX_PROMPT
@@ -241,7 +242,7 @@ def check_kernels(cfg, plan, tile, seed: int, interpret: bool) -> dict:
     qs = jax.random.normal(ks[4], (SLOTS, s, h, d), jnp.bfloat16)
     pmask = L.ragged_prefill_attention_mask(off, lens, s, n)
     kpn, vpn = poisoned(off + lens - 1)
-    got = flash_prefill_ragged(qs, kpn, vpn, bt, off, lens,
+    got = flash_prefill_ragged(qs, kpn[None], vpn[None], bt, off, lens, 0,
                                interpret=interpret,
                                block_q=tile["block_q"])
     pre_err = _rel_err(got, _attend_f32(qs, kf, vf, pmask))

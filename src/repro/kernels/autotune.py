@@ -346,10 +346,11 @@ def _fpd_inputs(problem_json: str, page_size: int):
     n_pages = slots * blocks + 1           # + the reserved scratch page
     q = jax.random.normal(jax.random.PRNGKey(0),
                           (slots, 1, h, d)).astype(dtype)
+    # a one-layer whole-model pool, attended at layer 0
     kp = jax.random.normal(jax.random.PRNGKey(1),
-                           (n_pages, kvh, page_size, d)).astype(dtype)
+                           (1, n_pages, kvh, page_size, d)).astype(dtype)
     vp = jax.random.normal(jax.random.PRNGKey(2),
-                           (n_pages, kvh, page_size, d)).astype(dtype)
+                           (1, n_pages, kvh, page_size, d)).astype(dtype)
     bt = 1 + jnp.arange(slots * blocks, dtype=jnp.int32).reshape(
         slots, blocks)
     # steady-state (worst-case) decode: every request near capacity
@@ -361,7 +362,7 @@ def _fpd_runner(problem: dict[str, Any], cfg: dict[str, int],
                 interpret: bool) -> Callable[[], Any]:
     q, kp, vp, bt, sl = _fpd_inputs(
         json.dumps(problem, sort_keys=True), cfg["page_size"])
-    return lambda: flash_decode_paged(q, kp, vp, bt, sl,
+    return lambda: flash_decode_paged(q, kp, vp, bt, sl, 0,
                                       interpret=interpret)
 
 
@@ -422,9 +423,9 @@ def _pseg_fn(problem_json: str, seg_len: int, interpret: bool):
     q = jax.random.normal(jax.random.PRNGKey(0),
                           (slots, 1, h, d)).astype(dtype)
     kp = jax.random.normal(jax.random.PRNGKey(1),
-                           (n_pages, kvh, ps, d)).astype(dtype)
+                           (1, n_pages, kvh, ps, d)).astype(dtype)
     vp = jax.random.normal(jax.random.PRNGKey(2),
-                           (n_pages, kvh, ps, d)).astype(dtype)
+                           (1, n_pages, kvh, ps, d)).astype(dtype)
     bt = 1 + jnp.arange(slots * blocks, dtype=jnp.int32).reshape(
         slots, blocks)
     # start half-full: the scan advances seq_lens like a real segment
@@ -434,7 +435,7 @@ def _pseg_fn(problem_json: str, seg_len: int, interpret: bool):
         def step(carry, _):
             cur = carry
             out = flash_decode_paged(q, kp, vp, bt,
-                                     jnp.minimum(cur, max_len - 1),
+                                     jnp.minimum(cur, max_len - 1), 0,
                                      interpret=interpret)
             return jnp.minimum(cur + 1, max_len - 1), out[:, 0, 0, 0]
         sl, outs = jax.lax.scan(step, sl, None, length=seg_len)
@@ -515,9 +516,9 @@ def _fpr_inputs(problem_json: str):
     q = jax.random.normal(jax.random.PRNGKey(0),
                           (slots, s, h, d)).astype(dtype)
     kp = jax.random.normal(jax.random.PRNGKey(1),
-                           (n_pages, kvh, ps, d)).astype(dtype)
+                           (1, n_pages, kvh, ps, d)).astype(dtype)
     vp = jax.random.normal(jax.random.PRNGKey(2),
-                           (n_pages, kvh, ps, d)).astype(dtype)
+                           (1, n_pages, kvh, ps, d)).astype(dtype)
     bt = 1 + jnp.arange(slots * blocks, dtype=jnp.int32).reshape(
         slots, blocks)
     # worst case: every suffix sits at the end of a near-full prefix, so
@@ -531,7 +532,7 @@ def _fpr_runner(problem: dict[str, Any], cfg: dict[str, int],
                 interpret: bool) -> Callable[[], Any]:
     q, kp, vp, bt, off, lens = _fpr_inputs(
         json.dumps(problem, sort_keys=True))
-    return lambda: flash_prefill_ragged(q, kp, vp, bt, off, lens,
+    return lambda: flash_prefill_ragged(q, kp, vp, bt, off, lens, 0,
                                         interpret=interpret,
                                         block_q=cfg["block_q"])
 

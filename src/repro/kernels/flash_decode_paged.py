@@ -9,10 +9,13 @@ fixed-size pages plus a per-request *block table* mapping logical block
 the serving engine admit/evict requests without ever copying or
 compacting KV state (src/repro/serving/).
 
-The pool is head-major, ``(P, KV, page_size, D)`` (the layout
-serving/paged_cache.py owns), so one grid step DMAs one head's page as a
-contiguous ``(page_size, D)`` tile — a block the TPU compiler accepts,
-where a ``(page_size, 1, D)`` slice of a token-major pool is refused.
+The pool is the whole model's, head-major, ``(L, P, KV, page_size, D)``
+(the layout serving/paged_cache.py owns), so one grid step DMAs one
+head's page of one layer as a contiguous ``(page_size, D)`` tile — a
+block the TPU compiler accepts, where a ``(page_size, 1, D)`` slice of a
+token-major pool is refused.  The layer is a scalar-prefetch operand the
+index maps read, so the layer scan hands the kernel the pool it carries
+and no layer's pages are ever sliced out of it.
 
 Mechanically the kv-split of flash_decode becomes the page: the grid is
 ``(slots, kv_heads, blocks_per_req)`` and the innermost dimension walks
@@ -78,10 +81,10 @@ def online_softmax_page(q, k, v, live, m_ref, l_ref, acc_ref) -> None:
     m_ref[...] = m_new
 
 
-def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, out_ref,
-                         m_ref, l_ref, acc_ref, *, blocks: int, ps: int,
-                         g: int, scale: float):
-    del bt_ref  # consumed by the BlockSpec index maps, not the body
+def _paged_decode_kernel(bt_ref, sl_ref, layer_ref, q_ref, k_ref, v_ref,
+                         out_ref, m_ref, l_ref, acc_ref, *, blocks: int,
+                         ps: int, g: int, scale: float):
+    del bt_ref, layer_ref  # consumed by the BlockSpec index maps
     ri = pl.program_id(0)
     j = pl.program_id(2)
 
@@ -95,7 +98,7 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, out_ref,
     def _step():
         kv_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (g, ps), 1)
         online_softmax_page(q_ref[0, 0].astype(jnp.float32) * scale,
-                            k_ref[0, 0], v_ref[0, 0], kv_pos <= sl,
+                            k_ref[0, 0, 0], v_ref[0, 0, 0], kv_pos <= sl,
                             m_ref, l_ref, acc_ref)
 
     @pl.when(j == blocks - 1)
@@ -106,20 +109,22 @@ def _paged_decode_kernel(bt_ref, sl_ref, q_ref, k_ref, v_ref, out_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def flash_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                        v_pages: jnp.ndarray, block_tables: jnp.ndarray,
-                       seq_lens: jnp.ndarray, *,
+                       seq_lens: jnp.ndarray, layer, *,
                        interpret: bool = False) -> jnp.ndarray:
-    """q: (R,1,H,D); k/v_pages: (P, KV, page_size, D) with H % KV == 0;
-    block_tables: (R, max_blocks) int32 physical page per logical block
-    (entries past a request's length must still be valid page indices —
-    the serving layer parks them on its reserved scratch page);
-    seq_lens: (R,) int32 — logical slots ``0..seq_lens[r]`` participate
-    (the slot at ``seq_lens[r]`` holds the current token).  Returns
+    """q: (R,1,H,D); k/v_pages: (L, P, KV, page_size, D), the whole
+    model's pools, with H % KV == 0; block_tables: (R, max_blocks) int32
+    physical page per logical block (entries past a request's length
+    must still be valid page indices — the serving layer parks them on
+    its reserved scratch page); seq_lens: (R,) int32 — logical slots
+    ``0..seq_lens[r]`` participate (the slot at ``seq_lens[r]`` holds the
+    current token); layer: the layer whose pages are attended, an int or
+    an int32 scalar (the layer scan's traced index).  Returns
     (R,1,H,D).  The page size is the kv-split: it is fixed by the pool
     layout, so it is tuned at pool-construction time
     (kernels/autotune.py ``flash_decode_paged``), not per call.
     """
     r, sq, h, d = q.shape
-    n_pages, kvh, ps, _ = k_pages.shape
+    _, _, kvh, ps, _ = k_pages.shape
     rt, blocks = block_tables.shape
     assert sq == 1, f"flash_decode_paged is single-token (got sq={sq})"
     assert h % kvh == 0, (h, kvh)
@@ -129,25 +134,25 @@ def flash_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     qf = q[:, 0].reshape(r, kvh, g, d)
     grid = (r, kvh, blocks)
 
-    def kv_map(ri, kv, j, bt, sl):
+    def kv_map(ri, kv, j, bt, sl, lyr):
         # pages past the last live one re-point at it: same block index
         # as the previous step, so the pipeline skips their copy
-        return (bt[ri, jnp.minimum(j, sl[ri] // ps)], kv, 0, 0)
+        return (lyr[0], bt[ri, jnp.minimum(j, sl[ri] // ps)], kv, 0, 0)
 
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, blocks=blocks, ps=ps, g=g,
                           scale=1.0 / math.sqrt(d)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, g, d),
-                             lambda ri, kv, j, bt, sl: (ri, kv, 0, 0)),
-                pl.BlockSpec((1, 1, ps, d), kv_map),
-                pl.BlockSpec((1, 1, ps, d), kv_map),
+                             lambda ri, kv, j, *_: (ri, kv, 0, 0)),
+                pl.BlockSpec((1, 1, 1, ps, d), kv_map),
+                pl.BlockSpec((1, 1, 1, ps, d), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, g, d),
-                                   lambda ri, kv, j, bt, sl: (ri, kv, 0, 0)),
+                                   lambda ri, kv, j, *_: (ri, kv, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((g, 1), jnp.float32),
                 pltpu.VMEM((g, 1), jnp.float32),
@@ -156,5 +161,6 @@ def flash_decode_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         ),
         out_shape=jax.ShapeDtypeStruct((r, kvh, g, d), q.dtype),
         interpret=interpret,
-    )(block_tables, seq_lens.astype(jnp.int32), qf, k_pages, v_pages)
+    )(block_tables, seq_lens.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), qf, k_pages, v_pages)
     return out.reshape(r, 1, h, d)

@@ -11,9 +11,10 @@ shared prefix) in one dispatch, reading K/V — shared prefix and freshly
 scattered suffix alike — straight out of the page pool through the block
 table.
 
-The pool is head-major, ``(P, KV, page_size, D)``
-(serving/paged_cache.py), so each grid step DMAs one head's page as a
-contiguous ``(page_size, D)`` tile.
+The pool is the whole model's, head-major, ``(L, P, KV, page_size, D)``
+(serving/paged_cache.py), so each grid step DMAs one head's page of one
+layer as a contiguous ``(page_size, D)`` tile; the layer rides in as a
+scalar-prefetch operand the index maps read.
 
 Grid: ``(slots, kv_heads, q_tiles, blocks)``.  The innermost dimension
 walks the request's block table exactly like the paged decode kernel,
@@ -60,10 +61,11 @@ from repro.kernels.flash_decode_paged import online_softmax_page
 BQ = 32
 
 
-def _ragged_prefill_kernel(bt_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
-                           out_ref, m_ref, l_ref, acc_ref, *, blocks: int,
-                           bq: int, ps: int, g: int, scale: float):
-    del bt_ref  # consumed by the BlockSpec index maps, not the body
+def _ragged_prefill_kernel(bt_ref, off_ref, len_ref, layer_ref, q_ref,
+                           k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref, *,
+                           blocks: int, bq: int, ps: int, g: int,
+                           scale: float):
+    del bt_ref, layer_ref  # consumed by the BlockSpec index maps
     ri = pl.program_id(0)
     qi = pl.program_id(2)
     j = pl.program_id(3)
@@ -92,7 +94,7 @@ def _ragged_prefill_kernel(bt_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
                                                    1)
         live = (kv_pos <= off + qrel) & (qrel < ln)
         online_softmax_page(q_ref[0, 0].astype(jnp.float32) * scale,
-                            k_ref[0, 0], v_ref[0, 0], live,
+                            k_ref[0, 0, 0], v_ref[0, 0, 0], live,
                             m_ref, l_ref, acc_ref)
 
     @pl.when(j == blocks - 1)
@@ -103,20 +105,22 @@ def _ragged_prefill_kernel(bt_ref, off_ref, len_ref, q_ref, k_ref, v_ref,
 @functools.partial(jax.jit, static_argnames=("interpret", "block_q"))
 def flash_prefill_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
                          v_pages: jnp.ndarray, block_tables: jnp.ndarray,
-                         offsets: jnp.ndarray, lens: jnp.ndarray, *,
-                         interpret: bool = False,
+                         offsets: jnp.ndarray, lens: jnp.ndarray, layer,
+                         *, interpret: bool = False,
                          block_q: int | None = None) -> jnp.ndarray:
-    """q: (R,S,H,D) suffix queries; k/v_pages: (P, KV, page_size, D) with
-    H % KV == 0; block_tables: (R, max_blocks) int32 (entries past a
-    request's pages parked on the serving layer's scratch page);
-    offsets/lens: (R,) int32 — absolute position of q[:, 0] (the shared
-    prefix length) and valid suffix tokens per request (0 = idle slot).
+    """q: (R,S,H,D) suffix queries; k/v_pages: (L, P, KV, page_size, D),
+    the whole model's pools, with H % KV == 0; block_tables:
+    (R, max_blocks) int32 (entries past a request's pages parked on the
+    serving layer's scratch page); offsets/lens: (R,) int32 — absolute
+    position of q[:, 0] (the shared prefix length) and valid suffix
+    tokens per request (0 = idle slot); layer: the layer whose pages are
+    attended, an int or an int32 scalar (the layer scan's traced index).
     Suffix K/V must already be scattered into the pages (the layer does
     this before attending, exactly like the decode path).  Returns
     (R,S,H,D); rows at or past ``lens`` are zero.
     """
     r, s, h, d = q.shape
-    n_pages, kvh, ps, _ = k_pages.shape
+    _, _, kvh, ps, _ = k_pages.shape
     rt, blocks = block_tables.shape
     assert h % kvh == 0, (h, kvh)
     assert rt == r, (rt, r)
@@ -133,25 +137,23 @@ def flash_prefill_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
     qf = qf.reshape(r, kvh, s_p * g, d)
     grid = (r, kvh, s_p // bq, blocks)
 
+    def kv_map(ri, kv, qi, j, bt, off, ln, lyr):
+        return (lyr[0], bt[ri, j], kv, 0, 0)
+
     out = pl.pallas_call(
         functools.partial(_ragged_prefill_kernel, blocks=blocks, bq=bq,
                           ps=ps, g=g, scale=1.0 / math.sqrt(d)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, bq * g, d),
-                             lambda ri, kv, qi, j, bt, off, ln:
-                             (ri, kv, qi, 0)),
-                pl.BlockSpec((1, 1, ps, d),
-                             lambda ri, kv, qi, j, bt, off, ln:
-                             (bt[ri, j], kv, 0, 0)),
-                pl.BlockSpec((1, 1, ps, d),
-                             lambda ri, kv, qi, j, bt, off, ln:
-                             (bt[ri, j], kv, 0, 0)),
+                             lambda ri, kv, qi, j, *_: (ri, kv, qi, 0)),
+                pl.BlockSpec((1, 1, 1, ps, d), kv_map),
+                pl.BlockSpec((1, 1, 1, ps, d), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, bq * g, d),
-                                   lambda ri, kv, qi, j, bt, off, ln:
+                                   lambda ri, kv, qi, j, *_:
                                    (ri, kv, qi, 0)),
             scratch_shapes=[
                 pltpu.VMEM((bq * g, 1), jnp.float32),
@@ -162,6 +164,6 @@ def flash_prefill_ragged(q: jnp.ndarray, k_pages: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((r, kvh, s_p * g, d), q.dtype),
         interpret=interpret,
     )(block_tables, offsets.astype(jnp.int32), lens.astype(jnp.int32),
-      qf, k_pages, v_pages)
+      jnp.asarray(layer, jnp.int32).reshape(1), qf, k_pages, v_pages)
     out = out.reshape(r, kvh, s_p, g, d).transpose(0, 2, 1, 3, 4)
     return out.reshape(r, s_p, h, d)[:, :s]

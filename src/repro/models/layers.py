@@ -381,9 +381,11 @@ def _paged_attention_decode(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     """One-token attention over a paged block-table cache (ragged batch).
 
     q/k/v: (R, 1, ·, hd) — already roped with per-request positions.  The
-    per-layer cache carries the page pool (``k_pages``/``v_pages`` in the
-    head-major layout serving/paged_cache.py owns) plus the batch-shared
-    ``block_tables`` (R, M) and ``seq_lens`` (R,) injected by lm_apply.
+    per-layer cache carries the whole model's page pools
+    (``k_pages``/``v_pages`` in the head-major layout
+    serving/paged_cache.py owns) and this block's ``layer`` index, plus
+    the batch-shared ``block_tables`` (R, M) and ``seq_lens`` (R,), all
+    injected by lm_apply.
     The current token's K/V is scattered into page
     ``block_tables[r, seq_lens[r] // ps]`` before attention, then each
     request attends its own prefix — the jnp oracle gathers pages through
@@ -396,9 +398,9 @@ def _paged_attention_decode(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     from repro.serving.paged_cache import gather_request_kv, write_token_kv
     assert not cfg.sliding_window, \
         "paged decode supports linear caches only"
-    kp, vp = cache["k_pages"], cache["v_pages"]
+    kp, vp, layer = cache["k_pages"], cache["v_pages"], cache["layer"]
     bt, sl = cache["block_tables"], cache["seq_lens"]
-    ps = kp.shape[2]
+    ps = kp.shape[3]
     r, _, h, _ = q.shape
     blocks = bt.shape[1]
     n_slots = blocks * ps
@@ -409,22 +411,23 @@ def _paged_attention_decode(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     pidx = bt[jnp.arange(r), pos_w // ps]
     slot = pos_w % ps
     with jax.named_scope("kv_write"):
-        kp = write_token_kv(kp, pidx, slot, k[:, 0])
-        vp = write_token_kv(vp, pidx, slot, v[:, 0])
+        kp = write_token_kv(kp, layer, pidx, slot, k[:, 0])
+        vp = write_token_kv(vp, layer, pidx, slot, v[:, 0])
     new_cache = dict(cache, k_pages=kp, v_pages=vp)
     seq_sharded = (ctx.mesh is not None
                    and "model" in ctx.mesh.axis_names
                    and _axis_size(ctx.mesh, "model") > 1)
     if ctx.use_kernels and not seq_sharded:
         from repro.kernels.flash_decode_paged import flash_decode_paged
-        out = flash_decode_paged(q, kp, vp, bt, sl,
+        out = flash_decode_paged(q, kp, vp, bt, sl, layer,
                                  interpret=ctx.interpret).astype(q.dtype)
     else:
         # jnp oracle: gather each request's pages into contiguous K/V
         mask = paged_decode_attention_mask(paged_kv_positions(sl, n_slots),
                                            sl)
-        out = _masked_decode_attention(q, gather_request_kv(kp, bt),
-                                       gather_request_kv(vp, bt), mask, h)
+        out = _masked_decode_attention(q, gather_request_kv(kp[layer], bt),
+                                       gather_request_kv(vp[layer], bt),
+                                       mask, h)
     return out, new_cache
 
 
@@ -455,10 +458,10 @@ def _paged_attention_prefill(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     from repro.serving.paged_cache import gather_request_kv, write_token_kv
     assert not cfg.sliding_window, \
         "paged prefill supports linear caches only"
-    kp, vp = cache["k_pages"], cache["v_pages"]
+    kp, vp, layer = cache["k_pages"], cache["v_pages"], cache["layer"]
     bt, off = cache["block_tables"], cache["seq_lens"]
     lens = cache["prefill_lens"]
-    _, kvh, ps, hd = kp.shape
+    _, _, kvh, ps, hd = kp.shape
     r, s, h, _ = q.shape
     blocks = bt.shape[1]
     n_slots = blocks * ps
@@ -471,8 +474,8 @@ def _paged_attention_prefill(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
     pidx = jnp.where(valid, bt[rows, pos_c // ps], 0)
     slot = pos_c % ps
     with jax.named_scope("kv_write"):
-        kp = write_token_kv(kp, pidx, slot, k)
-        vp = write_token_kv(vp, pidx, slot, v)
+        kp = write_token_kv(kp, layer, pidx, slot, k)
+        vp = write_token_kv(vp, layer, pidx, slot, v)
     new_cache = dict(cache, k_pages=kp, v_pages=vp)
     seq_sharded = (ctx.mesh is not None
                    and "model" in ctx.mesh.axis_names
@@ -484,14 +487,14 @@ def _paged_attention_prefill(ctx: Ctx, cfg: ArchConfig, q, k, v, cache):
             "flash_prefill_ragged",
             autotune.flash_prefill_ragged_problem(r, s, h, kvh, hd,
                                                   n_slots, ps, q.dtype))
-        out = flash_prefill_ragged(q, kp, vp, bt, off, lens,
+        out = flash_prefill_ragged(q, kp, vp, bt, off, lens, layer,
                                    interpret=ctx.interpret,
                                    block_q=tile["block_q"]).astype(q.dtype)
     else:
         # jnp oracle: gather each request's pages into contiguous K/V and
         # run the standard chunked-mea prefill with per-request positions
-        kf = gather_request_kv(kp, bt)
-        vf = gather_request_kv(vp, bt)
+        kf = gather_request_kv(kp[layer], bt)
+        vf = gather_request_kv(vp[layer], bt)
         out = mea_attention(q, _expand_kv(kf, h), _expand_kv(vf, h),
                             pos, jnp.arange(n_slots),
                             causal=True, chunk=cfg.attn_chunk,

@@ -11,7 +11,8 @@ Block taxonomy (cfg.family):
                 (conv frontend stubbed per spec), decoder self+cross attn
 
 Caches mirror the block structure, stacked along the layer axis so decode
-scans over (params, cache) together.
+scans over (params, cache) together — except the paged KV pool, which the
+layer scan carries whole and updates in place (:func:`_paged_stack`).
 """
 
 from __future__ import annotations
@@ -210,8 +211,8 @@ def lm_apply(ctx: Ctx, cfg: ArchConfig, params, tokens, positions=None,
         if cache is not None and ctx.decode:
             if "block_tables" in cache:
                 # paged cache (one sentinel key for the whole-model dict,
-                # matching the scan_cache branch below; the per-layer dict
-                # is detected by "k_pages" in layers.attention): ragged
+                # matching the _paged_stack branch below; the per-layer
+                # dict is detected by "k_pages" in layers.attention): ragged
                 # batch, per-request positions.  The serving engine owns
                 # the seq_lens increment (it knows which slots are
                 # active); lm_apply only reads them.  During a batched
@@ -230,31 +231,16 @@ def lm_apply(ctx: Ctx, cfg: ArchConfig, params, tokens, positions=None,
 
     if cfg.family == "hybrid":
         x, new_cache = _hybrid_stack(ctx, cfg, params, x, positions, cache)
+    elif cache is not None and "block_tables" in cache:
+        x, new_cache = _paged_stack(ctx, cfg, params, x, positions, cache)
     else:
-        paged = cache is not None and "block_tables" in cache
-
         def body(xcarry, xs):
             lp, lc = xs
-            if paged:
-                # block tables / seq_lens are batch state shared by every
-                # layer — injected here instead of stacked per layer.
-                # prefill_lens (per-request valid suffix lengths) rides
-                # along only during a batched ragged admission prefill
-                # dispatch; its presence is what routes layers.attention
-                # to the ragged-prefill branch.
-                lc = dict(lc, block_tables=cache["block_tables"],
-                          seq_lens=cache["seq_lens"])
-                if "prefill_lens" in cache:
-                    lc["prefill_lens"] = cache["prefill_lens"]
-            y, nc = block_fn(ctx, cfg, lp, xcarry, positions, lc)
-            if paged:
-                nc = {"k_pages": nc["k_pages"], "v_pages": nc["v_pages"]}
-            return y, nc
+            return block_fn(ctx, cfg, lp, xcarry, positions, lc)
 
         body = _remat(cfg, body)
         scan_cache = cache["blocks"] if (cache is not None
-                                         and (cfg.family == "ssm" or paged)
-                                         ) else cache
+                                         and cfg.family == "ssm") else cache
         if cfg.scan_layers:
             if cache is None:
                 x, new_scan_cache = jax.lax.scan(
@@ -278,10 +264,6 @@ def lm_apply(ctx: Ctx, cfg: ArchConfig, params, tokens, positions=None,
             new_cache = None
         elif cfg.family == "ssm":
             new_cache = {"blocks": new_scan_cache, "pos": cache["pos"] + s}
-        elif paged:
-            new_cache = {"blocks": new_scan_cache,
-                         "block_tables": cache["block_tables"],
-                         "seq_lens": cache["seq_lens"]}
         else:
             new_cache = new_scan_cache
 
@@ -290,6 +272,41 @@ def lm_apply(ctx: Ctx, cfg: ArchConfig, params, tokens, positions=None,
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         logits = linear(ctx, "lm_head", x, head)
     return logits, new_cache
+
+
+def _paged_stack(ctx: Ctx, cfg: ArchConfig, params, x, positions, cache):
+    """The layer stack over a paged cache.  The whole-model pools ride in
+    the carry beside ``x``, and each layer writes and reads its own pages
+    through its index, in place.  Scanned as xs/ys instead, every layer's
+    pages would be sliced out of the pool and written back into a fresh
+    one, which the enclosing decode loop then copies whole each step."""
+    # block tables / seq_lens are batch state shared by every layer.
+    # prefill_lens (per-request valid suffix lengths) rides along only
+    # during a batched ragged admission prefill dispatch; its presence is
+    # what routes layers.attention to the ragged-prefill branch.
+    shared = {k: cache[k] for k in ("block_tables", "seq_lens",
+                                    "prefill_lens") if k in cache}
+
+    def body(carry, xs):
+        xc, kp, vp = carry
+        lp, layer = xs
+        lc = dict(shared, k_pages=kp, v_pages=vp, layer=layer)
+        y, nc = lm_block_apply(ctx, cfg, lp, xc, positions, lc)
+        return (y, nc["k_pages"], nc["v_pages"]), None
+
+    body = _remat(cfg, body)
+    carry = (x, cache["blocks"]["k_pages"], cache["blocks"]["v_pages"])
+    if cfg.scan_layers:
+        carry, _ = jax.lax.scan(body, carry, (params["blocks"],
+                                              jnp.arange(cfg.n_layers)))
+    else:  # unrolled python loop (cost-analysis probes; see dryrun.py)
+        for i in range(cfg.n_layers):
+            lp = jax.tree.map(lambda t: t[i], params["blocks"])
+            carry, _ = body(carry, (lp, i))
+    x, kp, vp = carry
+    return x, {"blocks": {"k_pages": kp, "v_pages": vp},
+               "block_tables": cache["block_tables"],
+               "seq_lens": cache["seq_lens"]}
 
 
 def _cache_pos(cfg: ArchConfig, cache):

@@ -10,13 +10,14 @@ of ``page_size``-token pages, each request owns an ordered list of pages
 next admission reuses immediately.  That is the memory architecture the
 continuous-batching scheduler (serving/scheduler.py) allocates against.
 
-Layout per layer: ``k_pages``/``v_pages``: (n_pages, KV, page_size, hd),
+Layout: ``k_pages``/``v_pages``: (n_layers, n_pages, KV, page_size, hd),
 head-major, so one head's page is a contiguous ``(page_size, hd)`` tile —
 the block the paged Pallas kernels DMA per grid step, and a shape the TPU
 compiler accepts as a block (its last two dims are a multiple of
-(8, 128) or the whole array dim).  :func:`init_paged_cache` stacks the
-per-layer pools over layers exactly like the contiguous cache, so
-lm_apply's layer scan carries them unchanged.  This module is the one
+(8, 128) or the whole array dim).  lm_apply's layer scan carries the
+whole-model pools and a layer index; the token scatter and both kernels
+address ``[layer, page, head, slot]`` in place, so no layer's pages are
+ever sliced out or written back.  This module is the one
 place that knows the layout: :func:`pool_shape` builds it, and every
 reader and writer of pages (the layers' token scatter and oracle gather,
 the engine's serial-prefill reshape, page writes and copy-on-write fork,
@@ -508,10 +509,10 @@ def init_paged_cache(cfg: ArchConfig, pcfg: PagedCacheConfig,
                      dtype=jnp.bfloat16):
     """Whole-model paged cache pytree (+ logical axes).
 
-    ``blocks`` stacks the per-layer page pools on a leading layer axis —
-    the same shape contract as init_lm_cache, so lm_apply's scan carries
-    it directly; ``block_tables``/``seq_lens`` are batch state shared by
-    every layer and injected per layer inside the scan body.
+    ``blocks`` holds the page pools with a leading layer axis, carried
+    whole through lm_apply's layer scan; ``block_tables``/``seq_lens`` are
+    batch state shared by every layer and injected per layer inside the
+    scan body.
     """
     if not supports_paging(cfg):
         raise ValueError(f"{cfg.name}: family={cfg.family} "
@@ -541,12 +542,19 @@ def pool_shape(cfg: ArchConfig, pcfg: PagedCacheConfig) -> tuple:
             cfg.hd)
 
 
-def write_token_kv(pages, page_idx, slot, kv):
-    """Per-layer scatter of token K/V rows into a (P, KV, ps, hd) pool:
-    token ``i`` lands in page ``page_idx[i]`` at in-page slot
-    ``slot[i]``.  ``page_idx``/``slot`` share one index shape ``I`` and
-    ``kv`` is ``I + (KV, hd)``."""
-    return pages.at[page_idx, :, slot].set(kv.astype(pages.dtype))
+def write_token_kv(pool, layer, page_idx, slot, kv):
+    """Scatter token K/V rows into layer ``layer`` of the whole-model pool
+    (L, P, KV, ps, hd): token ``i`` lands in page ``page_idx[i]`` at
+    in-page slot ``slot[i]``.  ``page_idx``/``slot`` share one index
+    shape ``I`` and ``kv`` is ``I + (KV, hd)``.
+
+    One ``(hd,)`` row per (token, KV head): the update window is the
+    pool's minor dimension, so the scatter keeps the kernels' row-major
+    layout and updates the pool in place, where a ``(KV, hd)`` window
+    strides across heads and makes the compiler relay the pool."""
+    heads = jnp.arange(pool.shape[2])
+    return pool.at[layer, page_idx[..., None], heads,
+                   slot[..., None]].set(kv.astype(pool.dtype))
 
 
 def gather_request_kv(pages, block_tables):

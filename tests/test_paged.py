@@ -131,7 +131,8 @@ class TestPagedKernelVsOracle:
         seq_lens = [0, cap // 2, cap - 1]          # empty-ish / mid / full
         q, kp, vp, bt, sl = _paged_problem(
             KEY, slots, h, kvh, d, page_size, blocks, seq_lens)
-        out = flash_decode_paged(q, kp, vp, bt, sl, interpret=True)
+        out = flash_decode_paged(q, kp[None], vp[None], bt, sl, 0,
+                                 interpret=True)
         ref = _oracle(q, kp, vp, bt, sl)
         assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
@@ -140,7 +141,8 @@ class TestPagedKernelVsOracle:
         slots, blocks, ps, h, kvh, d = 4, 4, 8, 4, 2, 8
         q, kp, vp, bt, sl = _paged_problem(
             jax.random.PRNGKey(7), slots, h, kvh, d, ps, blocks, seq_lens)
-        out = flash_decode_paged(q, kp, vp, bt, sl, interpret=True)
+        out = flash_decode_paged(q, kp[None], vp[None], bt, sl, 0,
+                                 interpret=True)
         ref = _oracle(q, kp, vp, bt, sl)
         assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
@@ -155,10 +157,26 @@ class TestPagedKernelVsOracle:
                                   dtype=jnp.int32).reshape(slots, blocks)
         kp_i = kp.at[ident_bt.reshape(-1)].set(kp[bt.reshape(-1)])
         vp_i = vp.at[ident_bt.reshape(-1)].set(vp[bt.reshape(-1)])
-        out_s = flash_decode_paged(q, kp, vp, bt, sl, interpret=True)
-        out_i = flash_decode_paged(q, kp_i, vp_i, ident_bt, sl,
+        out_s = flash_decode_paged(q, kp[None], vp[None], bt, sl, 0,
                                    interpret=True)
+        out_i = flash_decode_paged(q, kp_i[None], vp_i[None], ident_bt, sl,
+                                   0, interpret=True)
         assert float(jnp.max(jnp.abs(out_s - out_i))) < 1e-6
+
+    @pytest.mark.parametrize("layer", [0, 2])
+    def test_layer_of_whole_model_pool(self, layer):
+        """Given a 3-layer pool and a layer, the kernel attends that
+        layer's pages, as the oracle reading ``pool[layer]`` does."""
+        slots, blocks, ps, h, kvh, d = 3, 3, 8, 4, 2, 8
+        q, kp, vp, bt, sl = _paged_problem(
+            jax.random.PRNGKey(13), slots, h, kvh, d, ps, blocks,
+            [4, 13, 23])
+        ks = jax.random.split(jax.random.PRNGKey(14), 2)
+        kp3 = jax.random.normal(ks[0], (3,) + kp.shape)
+        vp3 = jax.random.normal(ks[1], (3,) + vp.shape)
+        out = flash_decode_paged(q, kp3, vp3, bt, sl, layer, interpret=True)
+        ref = _oracle(q, kp3[layer], vp3[layer], bt, sl)
+        assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
 
 def _slot_probe_pool(bt, n_pages, kvh, ps):
@@ -194,7 +212,8 @@ class TestDecodePredicate:
         bt = perm[:slots * blocks].reshape(slots, blocks).astype(jnp.int32)
         kp, vp = _slot_probe_pool(bt, n_pages, kvh, ps)
         q = jax.random.normal(jax.random.fold_in(key, 2), (slots, 1, h, n))
-        out = flash_decode_paged(q, kp, vp, bt, sl, interpret=True)
+        out = flash_decode_paged(q, kp[None], vp[None], bt, sl, 0,
+                                 interpret=True)
         mask = L.paged_decode_attention_mask(L.paged_kv_positions(sl, n), sl)
         attended = np.asarray(out[:, 0] > 0)                  # (R, H, n)
         want = np.broadcast_to(np.asarray(mask)[:, None], attended.shape)
@@ -212,8 +231,10 @@ class TestDecodePredicate:
         assert dead
         kp_nan = kp.at[jnp.asarray(dead)].set(jnp.nan)
         vp_nan = vp.at[jnp.asarray(dead)].set(jnp.nan)
-        out = flash_decode_paged(q, kp_nan, vp_nan, bt, sl, interpret=True)
-        ref = flash_decode_paged(q, kp, vp, bt, sl, interpret=True)
+        out = flash_decode_paged(q, kp_nan[None], vp_nan[None], bt, sl, 0,
+                                 interpret=True)
+        ref = flash_decode_paged(q, kp[None], vp[None], bt, sl, 0,
+                                 interpret=True)
         assert bool(jnp.all(jnp.isfinite(out)))
         assert float(jnp.max(jnp.abs(out - ref))) == 0.0
 
@@ -237,8 +258,8 @@ class TestPoolLayout:
         pos = np.array([[0, 5, 7], [2, 3, 4]])              # (R, S)
         kv = rng.normal(size=pos.shape + (kvh, hd)).astype(np.float32)
         rows = np.arange(2)[:, None]
-        pages = write_token_kv(jnp.zeros((n_pages, kvh, ps, hd)),
-                               bt[rows, pos // ps], pos % ps, kv)
+        pages = write_token_kv(jnp.zeros((1, n_pages, kvh, ps, hd)), 0,
+                               bt[rows, pos // ps], pos % ps, kv)[0]
         want = np.zeros((n_pages, kvh, ps, hd), np.float32)
         for r in range(2):
             for i, p in enumerate(pos[r]):
@@ -249,6 +270,54 @@ class TestPoolLayout:
         for r in range(2):
             for i, p in enumerate(pos[r]):
                 assert np.array_equal(got[r, p], kv[r, i])
+
+    @pytest.mark.parametrize("layer", [0, 1, 2])
+    def test_token_scatter_writes_one_layer(self, layer):
+        """A token write leaves every other layer bit-identical, and on its
+        own layer equals the per-layer write of one (KV, hd) window per
+        token."""
+        n_layers, n_pages, kvh, ps, hd = 3, 6, 2, 4, 3
+        rng = np.random.default_rng(2)
+        pool = rng.normal(size=(n_layers, n_pages, kvh, ps,
+                                hd)).astype(np.float32)
+        pidx = np.array([3, 1, 5, 3], np.int32)
+        slot = np.array([0, 2, 3, 1], np.int32)
+        kv = rng.normal(size=(4, kvh, hd)).astype(np.float32)
+        got = np.asarray(write_token_kv(jnp.asarray(pool), layer,
+                                        jnp.asarray(pidx),
+                                        jnp.asarray(slot),
+                                        jnp.asarray(kv)))
+        per_layer = jnp.asarray(pool[layer]).at[pidx, :, slot].set(kv)
+        for i in range(n_layers):
+            want = np.asarray(per_layer) if i == layer else pool[i]
+            assert np.array_equal(got[i], want)
+
+    def test_unrolled_stack_equals_scan(self):
+        """The unrolled layer loop (the cost probes' route) takes the same
+        paged body as the scan: same logits, same pools (float32
+        activations, so that fusion choices round nothing)."""
+        cfg = get_config("qwen2_7b", smoke=True).replace(act_dtype="float32")
+        model = build_model(cfg)
+        params = model.init(KEY)
+        pcfg = PagedCacheConfig(page_size=4, n_pages=9, max_slots=2,
+                                max_blocks=4)
+        cache, _ = init_paged_cache(cfg, pcfg, jnp.float32)
+        shape = cache["blocks"]["k_pages"].shape
+        ks = jax.random.split(jax.random.PRNGKey(21), 2)
+        cache = dict(cache, blocks={
+            "k_pages": jax.random.normal(ks[0], shape),
+            "v_pages": jax.random.normal(ks[1], shape)},
+            block_tables=jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]],
+                                     jnp.int32),
+            seq_lens=jnp.asarray([5, 10], jnp.int32))
+        tok = jnp.asarray([[3], [7]], jnp.int32)
+        unrolled = build_model(cfg.replace(scan_layers=False))
+        lg_s, c_s = jax.jit(model.decode_step)(params, cache, tok)
+        lg_u, c_u = jax.jit(unrolled.decode_step)(params, cache, tok)
+        assert np.allclose(np.asarray(lg_s), np.asarray(lg_u), atol=1e-5)
+        for name in ("k_pages", "v_pages"):
+            assert np.allclose(np.asarray(c_s["blocks"][name]),
+                               np.asarray(c_u["blocks"][name]), atol=1e-6)
 
     def test_page_images_round_trip(self):
         n_layers, kvh, ps, hd = 2, 2, 4, 3
@@ -652,8 +721,8 @@ class TestRaggedPrefillKernel:
         lens = [8, 5, 0]                         # full / ragged / idle
         q, kp, vp, bt, off, ln = _ragged_problem(
             KEY, slots, s, h, kvh, d, page_size, blocks, offs, lens)
-        out = flash_prefill_ragged(q, kp, vp, bt, off, ln, interpret=True,
-                                   block_q=4)
+        out = flash_prefill_ragged(q, kp[None], vp[None], bt, off, ln, 0,
+                                   interpret=True, block_q=4)
         ref = _ragged_oracle(q, kp, vp, bt, off, ln)
         assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
@@ -667,8 +736,8 @@ class TestRaggedPrefillKernel:
         q, kp, vp, bt, off, ln = _ragged_problem(
             jax.random.PRNGKey(3), slots, s, h, kvh, d, ps, blocks, offs,
             lens)
-        out = flash_prefill_ragged(q, kp, vp, bt, off, ln, interpret=True,
-                                   block_q=block_q)
+        out = flash_prefill_ragged(q, kp[None], vp[None], bt, off, ln, 0,
+                                   interpret=True, block_q=block_q)
         ref = _ragged_oracle(q, kp, vp, bt, off, ln)
         assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
@@ -681,7 +750,8 @@ class TestRaggedPrefillKernel:
         q, kp, vp, bt, off, ln = _ragged_problem(
             jax.random.PRNGKey(5), slots, s, h, kvh, d, ps, blocks, offs,
             lens)
-        out = flash_prefill_ragged(q, kp, vp, bt, off, ln, interpret=True)
+        out = flash_prefill_ragged(q, kp[None], vp[None], bt, off, ln, 0,
+                                   interpret=True)
         ref = _ragged_oracle(q, kp, vp, bt, off, ln)
         assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
         # causal frontier sanity directly on the helper
@@ -689,6 +759,22 @@ class TestRaggedPrefillKernel:
         assert mask[0, 0].astype(int).sum() == offs[0] + 1
         assert mask[1, 2].astype(int).sum() == offs[1] + 3
         assert not bool(mask[1, 3].any())        # past lens: dead row
+
+    @pytest.mark.parametrize("layer", [0, 2])
+    def test_layer_of_whole_model_pool(self, layer):
+        """Given a 3-layer pool and a layer, the kernel attends that
+        layer's pages, as the oracle reading ``pool[layer]`` does."""
+        slots, s, h, kvh, d, ps, blocks = 3, 8, 4, 2, 8, 4, 4
+        q, kp, vp, bt, off, ln = _ragged_problem(
+            jax.random.PRNGKey(17), slots, s, h, kvh, d, ps, blocks,
+            [0, 4, 8], [8, 5, 3])
+        ks = jax.random.split(jax.random.PRNGKey(18), 2)
+        kp3 = jax.random.normal(ks[0], (3,) + kp.shape)
+        vp3 = jax.random.normal(ks[1], (3,) + vp.shape)
+        out = flash_prefill_ragged(q, kp3, vp3, bt, off, ln, layer,
+                                   interpret=True, block_q=4)
+        ref = _ragged_oracle(q, kp3[layer], vp3[layer], bt, off, ln)
+        assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
 
 
 # --------------------------------------- prefix-sharing engine behavior
