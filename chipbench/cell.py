@@ -2,8 +2,9 @@
 
 ``BENCHMARK.json`` names the cell's configuration and traffic mix; the
 configuration is ``configs/<config>.json``, the mix ``traffic/<mix>.json``,
-and each per-layer metric's reader ``metrics/<name>.py``.  Adding a cell,
-a mix or a metric adds files and edits none.
+the configuration's architecture ``archs/<model_type>.py``, and each
+per-layer metric's reader ``metrics/<name>.py``.  Adding a cell, a mix, a
+metric or an architecture adds files and edits none.
 """
 
 from __future__ import annotations
@@ -12,6 +13,8 @@ import dataclasses
 import importlib.util
 import json
 import os
+import re
+import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -51,7 +54,7 @@ def find_cell(name: str, root: str = ROOT) -> Cell:
     return Cell(
         name=name,
         config=load_json(os.path.join(root, conf["file"])),
-        traffic=load_json(os.path.join(HERE, "traffic",
+        traffic=load_json(os.path.join(root, "chipbench", "traffic",
                                        w["traffic"] + ".json")),
         chips=int(w["chips"]),
         end_to_end=tuple(m for m in bench["end_to_end"]
@@ -68,54 +71,61 @@ def metric_reader(name: str):
     path = os.path.join(HERE, "metrics", name + ".py")
     if not os.path.exists(path):
         path = os.path.join(HERE, "metrics", name.split(".")[0] + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_"), path)
+    return _module(path, "chipbench_metric_" + name.replace(".", "_"))
+
+
+def _module(path: str, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod       # dataclasses look their module up there
     spec.loader.exec_module(mod)
     return mod
 
 
 # ------------------------------------------------------------ architecture
-@dataclasses.dataclass(frozen=True)
-class Arch:
-    """The published shape of a dense decoder, read from its config file:
-    what the weight draw, the reference and the work counts need."""
-    name: str
-    d: int
-    n_heads: int
-    n_kv: int
-    hd: int
-    ff: int
-    vocab: int
-    layers: int
-    norm: str            # "rms" | "layer"
-    eps: float
-    glu: bool            # SwiGLU (gate, up, down) vs GELU-tanh (fc, proj)
-    qkv_bias: bool
-    mlp_bias: bool
-    tied: bool
-    rope_theta: float
+_PLAIN = re.compile(r"[A-Za-z0-9_][A-Za-z0-9_.-]*")
 
 
-def arch(config: dict) -> Arch:
-    kind = config["model_type"]
-    if kind == "qwen2":
-        norm, eps, glu, qkv_b, mlp_b = ("rms", config["rms_norm_eps"], True,
-                                        True, False)
-    elif kind == "starcoder2":
-        norm, eps, glu = "layer", config["norm_epsilon"], False
-        qkv_b = mlp_b = bool(config["use_bias"])
-    else:
-        raise ValueError(f"no reference for model_type {kind!r}")
-    d, h = config["hidden_size"], config["num_attention_heads"]
-    return Arch(name=config["name"], d=d, n_heads=h,
-                n_kv=config["num_key_value_heads"],
-                hd=config.get("head_dim", d // h),
-                ff=config["intermediate_size"], vocab=config["vocab_size"],
-                layers=config["num_hidden_layers"], norm=norm,
-                eps=float(eps), glu=glu, qkv_bias=qkv_b, mlp_bias=mlp_b,
-                tied=bool(config["tie_word_embeddings"]),
-                rope_theta=float(config["rope_theta"]))
+def arch_plugin(model_type: str, root: str = ROOT):
+    """The module ``archs/<model_type>.py``, found from a configuration's
+    ``model_type`` as a metric's reader is found from its name.  It holds
+    what one architecture variant needs and nothing shared:
+
+    - ``arch(config) -> Arch``: the shape, read from the configuration
+      file, refusing keys its equations do not cover.  ``Arch`` is a
+      hashable (frozen) dataclass, a static argument of the jitted draw
+      and reference, with ``d``, ``vocab``, ``layers``, ``n_heads``, ``hd``
+      and ``rope_theta`` and these methods:
+    - ``draw(key)``: every parameter in the published layout, ``embed``
+      (vocabulary rows) and ``layers`` (stacked on a leading axis) among
+      them, built on ``weights.py``'s helpers; ``to_program(w)``: the
+      program's parameter tree for them;
+    - ``layer(lw, x, pos)`` and ``logits(w, h)``: one decoder layer on
+      x: (S, d) and the head on rows h, in float32 at ``highest``
+      precision (``reference.mm``);
+    - ``layer_matmul_params()``, ``kv_token_bytes()`` and
+      ``attended(context)``: the work counts (``work.py``);
+    - ``check_program(cfg)``: each field on which the program's own
+      ArchConfig differs from the file, ``name -> (program's, file's)``.
+    """
+    if not _PLAIN.fullmatch(model_type):
+        raise ValueError(f"model_type {model_type!r} is not a plain name")
+    path = os.path.join(root, "chipbench", "archs", model_type + ".py")
+    name = "chipbench_arch_" + model_type
+    # loaded once, so that its Arch class, a jit cache key, stays one
+    # object within a process
+    mod = sys.modules.get(name)
+    if mod is None or mod.__file__ != path:
+        if not os.path.exists(path):
+            raise ValueError(f"no plug-in for model_type {model_type!r}: "
+                             f"{path} not found")
+        mod = _module(path, name)
+    return mod
+
+
+def arch(config: dict, root: str = ROOT):
+    """The configuration's ``Arch``, from its ``model_type``'s plug-in."""
+    return arch_plugin(config["model_type"], root).arch(config)
 
 
 def program_config(config: dict, smoke: bool = False):
@@ -128,14 +138,9 @@ def program_config(config: dict, smoke: bool = False):
     cfg = get_config(config["arch"], smoke=smoke).replace(
         n_layers=a.layers, rope_theta=a.rope_theta,
         param_dtype="bfloat16", act_dtype="bfloat16")
-    have = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.hd, cfg.d_ff,
-            cfg.vocab_size, cfg.tie_embeddings, cfg.qkv_bias,
-            cfg.norm == ("rmsnorm" if a.norm == "rms" else "layernorm"),
-            cfg.mlp == ("glu" if a.glu else "gelu_mlp"),
-            bool(cfg.mlp_bias) == a.mlp_bias)
-    want = (a.d, a.n_heads, a.n_kv, a.hd, a.ff, a.vocab, a.tied,
-            a.qkv_bias, True, True, True)
-    if have != want:
+    off = a.check_program(cfg)
+    if off:
         raise ValueError(f"{config['name']}: the program's {config['arch']} "
-                         f"config {have} differs from the file {want}")
+                         f"config differs from the file (program's, file's): "
+                         f"{off}")
     return cfg

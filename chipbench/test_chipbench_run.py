@@ -16,9 +16,10 @@ from chipbench.run import Compiles, main, run_cell
 from chipbench.peaks import PEAKS, UnknownDevice, peaks_for
 from chipbench.smoke import END_TO_END, smoke_cell
 
-# (cell, arrival law): the offline law runs on the chat mix's lengths
+# (cell, arrival law): the offline law runs on the chat mix's lengths; the
+# sessions mix fills its prefixes before the window
 CELLS = {"qwen2-7b.chat": ("qwen2-7b.chat", None),
-         "starcoder2-3b.repo": ("starcoder2-3b.repo", None),
+         "qwen2-7b.sessions": ("qwen2-7b.sessions", None),
          "qwen2-7b.offline": ("qwen2-7b.chat", "offline")}
 KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
@@ -92,7 +93,7 @@ def test_serves_each_mix_and_assembles_the_result(name, capsys):
 
 
 def test_traced_run_reports_per_layer_metrics_it_can_read():
-    res = smoke_run("starcoder2-3b.repo", trace_on=True)
+    res = smoke_run("qwen2-7b.sessions", trace_on=True)
     assert set(res) == set(KEYS) | {"breakdown", "compared"}
     # no TPU plane off the chip: a reader with nothing to read is left out
     names = {m["name"] for m in C.benchmark()["per_layer"]}

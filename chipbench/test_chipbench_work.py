@@ -1,19 +1,22 @@
-"""The needed-work counts on small cases worked by hand."""
+"""The needed-work counts on small cases worked by hand, and at the chat
+cell's widths on fixed rows."""
 
+import pytest
+
+from chipbench import cell as C
 from chipbench import work
-from chipbench.cell import Arch
 
 # d=4, 2 heads of 2 over 1 KV head, SwiGLU of 8, vocabulary 10, one layer:
 # projections 4*2*(2*2 + 2*1) = 48 and MLP 3*4*8 = 96 -> 144 parameters.
-A = Arch(name="tiny", d=4, n_heads=2, n_kv=1, hd=2, ff=8, vocab=10,
-         layers=1, norm="rms", eps=1e-6, glu=True, qkv_bias=True,
-         mlp_bias=False, tied=False, rope_theta=1e4)
+A = C.arch_plugin("qwen2").Arch(d=4, n_heads=2, n_kv=1, hd=2, ff=8,
+                                vocab=10, layers=1, eps=1e-6,
+                                rope_theta=1e4)
 LM_HEAD = 2 * 4 * 10          # one position's logits
 KV_TOKEN = 2 * 1 * 2 * 2      # K and V of one token, bf16
 
 
 def test_layer_params():
-    assert work.layer_matmul_params(A) == 144
+    assert A.layer_matmul_params() == 144
 
 
 def test_admission_counts_valid_tokens_and_one_lm_head():
@@ -63,3 +66,26 @@ def test_decode_shared_prefix_read_once_per_step():
             {"context": 18, "shared": 16, "group": 1}]
     w = work.decode_step(A, rows)
     assert w.attn_bytes == 2 * (2 * 4 * 2) + (4 + 2 + 16) * KV_TOKEN
+
+
+# qwen2-7b's own widths (18 layers) on fixed rows: session rows, a lone
+# prefix, and an inactive row.  Pinned: what the chat cell's rooflines and
+# MFU divide by must not move unless the count is meant to.
+ADMISSION = [{"offset": 0, "length": 300, "group": None},
+             {"offset": 512, "length": 96, "group": 3},
+             {"offset": 512, "length": 40, "group": 3},
+             {"offset": 256, "length": 17, "group": None}]
+SEGMENT = [{"context0": 311, "emitted": 8, "shared": 0, "group": None},
+           {"context0": 700, "emitted": 5, "shared": 512, "group": 3},
+           {"context0": 640, "emitted": 8, "shared": 512, "group": 3},
+           {"context0": 90, "emitted": 0, "shared": 0, "group": None}]
+PINNED = {"admission": (3837069250560.0, 32194842624.0, 161906688.0),
+          "segment": (201961525248.0, 2888847360.0, 323739648.0)}
+
+
+@pytest.mark.parametrize("kind", PINNED)
+def test_chat_cell_counts_are_pinned(kind):
+    a = C.arch(C.find_cell("qwen2-7b.chat").config)
+    w = (work.admission(a, ADMISSION) if kind == "admission"
+         else work.segment(a, SEGMENT))
+    assert (w.flops, w.attn_flops, w.attn_bytes) == PINNED[kind]

@@ -14,13 +14,18 @@ Norms, RoPE and softmax are left out, as MFU conventionally leaves them.
 Bytes are what the attention kernels must move through HBM: each query and
 output row once, and each live K/V token once per step and layer.  Sizes
 are in bfloat16 (2 bytes).
+
+What differs between architectures comes from the configuration's plug-in
+(``cell.arch_plugin``): the matmul parameters a token meets in one layer
+(``Arch.layer_matmul_params``), the K/V bytes one token keeps in one layer
+(``Arch.kv_token_bytes``) and how many tokens a query attends at a given
+context, itself included (``Arch.attended``: the context itself under full
+attention, at most the window under a sliding one).
 """
 
 from __future__ import annotations
 
 import dataclasses
-
-from chipbench.cell import Arch
 
 BYTES = 2
 
@@ -38,67 +43,64 @@ class Work:
         return self
 
 
-def layer_matmul_params(a: Arch) -> int:
-    attn = a.d * a.hd * (2 * a.n_heads + 2 * a.n_kv)
-    return attn + a.d * a.ff * (3 if a.glu else 2)
-
-
-def _kv_token_bytes(a: Arch) -> int:
-    return 2 * a.n_kv * a.hd * BYTES          # K and V of one token
-
-
-def admission(a: Arch, rows: list[dict]) -> Work:
+def admission(a, rows: list[dict]) -> Work:
     """One boundary's admissions.  Each row: ``offset`` (shared prefix
     tokens already in the pool), ``length`` (suffix tokens computed) and
     ``group`` (rows of one group share their prefix pages; None: alone).
-    Each row attends causally over its prefix and its own suffix."""
+    Each query attends causally over the prefix and suffix tokens that
+    ``Arch.attended`` lets it reach."""
     w = Work()
-    p, L, hq = layer_matmul_params(a), a.layers, a.n_heads * a.hd
+    p, L, hq = a.layer_matmul_params(), a.layers, a.n_heads * a.hd
     kv_tokens = 0
     prefixes = {}
     for r in rows:
         o, n = int(r["offset"]), int(r["length"])
         if n <= 0:
             continue
-        pairs = n * o + n * (n + 1) // 2
+        pairs = sum(a.attended(o + j) for j in range(1, n + 1))
+        # the prefix tokens its first query attends: no later one
+        # attends an earlier token
+        seen = o - max(0, o + 1 - a.attended(o + 1))
         attn = 4 * hq * pairs * L
         w.flops += 2 * p * n * L + attn + 2 * a.d * a.vocab
         w.attn_flops += attn
         w.attn_bytes += 2 * n * hq * BYTES * L          # q in, out
         kv_tokens += n
         if r.get("group") is None:
-            kv_tokens += o
+            kv_tokens += seen
         else:
-            prefixes[r["group"]] = max(prefixes.get(r["group"], 0), o)
+            prefixes[r["group"]] = max(prefixes.get(r["group"], 0), seen)
     w.attn_bytes += (kv_tokens + sum(prefixes.values())) \
-        * _kv_token_bytes(a) * L
+        * a.kv_token_bytes() * L
     return w
 
 
-def decode_step(a: Arch, rows: list[dict]) -> Work:
+def decode_step(a, rows: list[dict]) -> Work:
     """One decode step of the active rows.  Each row: ``context`` (tokens
     attended, the current one included), ``shared`` (how many of them are a
     prefix shared within ``group``) and ``group``."""
     w = Work()
-    p, L, hq = layer_matmul_params(a), a.layers, a.n_heads * a.hd
+    p, L, hq = a.layer_matmul_params(), a.layers, a.n_heads * a.hd
     kv_tokens = 0
     prefixes = {}
     for r in rows:
         c = int(r["context"])
-        attn = 4 * hq * c * L
+        m = a.attended(c)
+        attn = 4 * hq * m * L
         w.flops += 2 * p * L + attn + 2 * a.d * a.vocab
         w.attn_flops += attn
         w.attn_bytes += 2 * hq * BYTES * L
         s = int(r.get("shared", 0)) if r.get("group") is not None else 0
-        kv_tokens += c - s
+        s = max(0, s - (c - m))         # the shared tokens it attends
+        kv_tokens += m - s
         if s:
             prefixes[r["group"]] = max(prefixes.get(r["group"], 0), s)
     w.attn_bytes += (kv_tokens + sum(prefixes.values())) \
-        * _kv_token_bytes(a) * L
+        * a.kv_token_bytes() * L
     return w
 
 
-def segment(a: Arch, rows: list[dict]) -> Work:
+def segment(a, rows: list[dict]) -> Work:
     """One decode segment.  Each row: ``context0`` (tokens in the pool
     before the segment's first step, the fed token excluded), ``emitted``
     (tokens it produced in the segment; 0 for an inactive row, which then
